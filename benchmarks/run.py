@@ -46,6 +46,9 @@ def main() -> None:
              "committed BENCH_serving*.json",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.oversubscribe:
         import json
 

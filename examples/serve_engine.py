@@ -95,6 +95,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, get_config
 from repro.serving import GenerationParams
 from repro.serving.engine import EngineConfig, Request, ServeEngine
@@ -146,6 +147,7 @@ def main():
                     help="record the request-lifecycle trace and export it to "
                          "FILE as Chrome trace-event JSON (view in Perfetto)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(get_config(args.arch, smoke=True), dtype="float32")
     model = build_model(cfg)

@@ -232,16 +232,29 @@ def _init_normal(stddev: float) -> InitFn:
     return f
 
 
-def _init_fan_in(key, shape, dtype):
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    std = 1.0 / math.sqrt(max(fan_in, 1))
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+def _init_fan_in(in_axes: Tuple[int, ...]) -> InitFn:
+    """Normal with std 1/sqrt(fan_in), fan_in the product of the contracted
+    axes ``in_axes``. They count from the end, so the leading layer axis of a
+    stacked spec is never counted."""
+
+    def f(key, shape, dtype):
+        fan_in = math.prod(shape[a] for a in in_axes) if len(shape) >= 2 else shape[-1]
+        std = 1.0 / math.sqrt(max(fan_in, 1))
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+    return f
 
 
 INITS: Dict[str, Any] = {
     "zeros": _init_zeros,
     "ones": _init_ones,
-    "fan_in": _init_fan_in,
+    # (…, d_in, d_out) matrices
+    "fan_in": _init_fan_in((-2,)),
+    # head-factored attention weights: (…, d, heads, head_dim) projections
+    # into the heads contract d; (…, heads, head_dim, d) out of them contract
+    # heads × head_dim
+    "fan_in_to_heads": _init_fan_in((-3,)),
+    "fan_in_from_heads": _init_fan_in((-3, -2)),
     "embed": _init_normal(0.02),
     "normal": _init_normal(0.02),
 }

@@ -2,18 +2,15 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-# Kernels are TPU-targeted; on CPU (this container) they execute via the Pallas
-# interpreter for correctness validation. On a real TPU backend set
-# REPRO_PALLAS_INTERPRET=0 (the default resolves by backend).
+
 def use_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """Kernels are TPU-targeted: they compile for the chip on a TPU backend
+    and run through the Pallas interpreter elsewhere. Callers that want the
+    interpreter on a TPU pass ``interpret=True`` to the kernel."""
     return jax.default_backend() != "tpu"
 
 

@@ -67,10 +67,12 @@ def pack_int4_splithalf(q: jax.Array) -> jax.Array:
 
 
 def unpack_int4_splithalf(b: jax.Array) -> jax.Array:
-    """Inverse of pack_int4_splithalf; sign-extends via arithmetic shifts."""
-    lo = (b << 4).astype(jnp.int8) >> 4
-    hi = b >> 4
-    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
+    """Inverse of pack_int4_splithalf, as int32 values in [-8, 7]. Sign-extends
+    via arithmetic shifts in int32: the TPU compiler refuses int8 shifts."""
+    x = b.astype(jnp.int32)
+    lo = (x << 28) >> 28
+    hi = x >> 4
+    return jnp.concatenate([lo, hi], axis=-1)
 
 
 def dequantize_pages(q: jax.Array, scale: jax.Array, *, bits: int) -> jax.Array:
@@ -79,6 +81,15 @@ def dequantize_pages(q: jax.Array, scale: jax.Array, *, bits: int) -> jax.Array:
     if bits == 4:
         q = unpack_int4_splithalf(q)
     return q.astype(jnp.float32) * scale[..., None, None]
+
+
+def _head_scale(s_ref, h):
+    """(1, 1) scale of kv head ``h`` from a page's (1, 1, Hkv) scale row. The
+    row is DMA'd whole because the TPU tiling rule refuses a (1, 1) block of
+    the (num_pages, Hkv) array; a lane mask selects the head."""
+    row = s_ref[0]  # (1, Hkv)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == h, row, 0.0), axis=1, keepdims=True)
 
 
 def _flash_update(q, k, v, live, acc_ref, m_ref, l_ref, *, scale):
@@ -336,9 +347,9 @@ def _paged_quant_decode_kernel(
     len_ref,   # scalar prefetch: (B,) int32 live token counts
     q_ref,     # (1, 1, G, D)
     kq_ref,    # (1, page_size, Dq) int8 — physical page picked by the index map
-    ks_ref,    # (1,) f32 — that page's per-head K scale
+    ks_ref,    # (1, 1, Hkv) f32 — that page's K scales, one per kv head
     vq_ref,    # (1, page_size, Dq) int8
-    vs_ref,    # (1,) f32
+    vs_ref,    # (1, 1, Hkv) f32
     o_ref,     # (1, 1, G, D)
     acc_ref,   # (G, D) f32
     m_ref,     # (G, 1) f32
@@ -349,7 +360,7 @@ def _paged_quant_decode_kernel(
     bits: int,
     block_pages: int,
 ):
-    b = pl.program_id(0)
+    b, h = pl.program_id(0), pl.program_id(1)
     jb, ji = pl.program_id(2), pl.program_id(3)
     j = jb * block_pages + ji
     last = (jb == pl.num_programs(2) - 1) & (ji == pl.num_programs(3) - 1)
@@ -373,8 +384,8 @@ def _paged_quant_decode_kernel(
         if bits == 4:
             kq = unpack_int4_splithalf(kq)   # lane concat: (page_size, D)
             vq = unpack_int4_splithalf(vq)
-        k = kq.astype(jnp.float32) * ks_ref[0]
-        v = vq.astype(jnp.float32) * vs_ref[0]
+        k = kq.astype(jnp.float32) * _head_scale(ks_ref, h)
+        v = vq.astype(jnp.float32) * _head_scale(vs_ref, h)
         _flash_update(q, k, v, live, acc_ref, m_ref, l_ref, scale=scale)
 
     @pl.when(last)
@@ -432,8 +443,9 @@ def paged_flash_decode_quant(
         (1, None, page_size, dq),
         lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], h, 0, 0),
     )
+    # scales viewed (num_pages, 1, Hkv): the block is a page's whole row
     scale_spec = pl.BlockSpec(
-        (1, None), lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], h)
+        (1, 1, hkv), lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -461,7 +473,8 @@ def paged_flash_decode_quant(
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-        qg, k_q, k_scale, v_q, v_scale,
+        qg, k_q, k_scale.reshape(num_pages, 1, hkv),
+        v_q, v_scale.reshape(num_pages, 1, hkv),
     )
     return out.reshape(b, hq, 1, d)
 
@@ -704,9 +717,9 @@ def _paged_chunk_quant_kernel(
     ck_ref,    # (1, 1, C, D) f32 — the chunk's own K, never from the pool
     cv_ref,    # (1, 1, C, D) f32
     kq_ref,    # (1, page_size, Dq) int8 — physical page picked by the index map
-    ks_ref,    # (1,) f32 — that page's per-head K scale
+    ks_ref,    # (1, 1, Hkv) f32 — that page's K scales, one per kv head
     vq_ref,    # (1, page_size, Dq) int8
-    vs_ref,    # (1,) f32
+    vs_ref,    # (1, 1, Hkv) f32
     o_ref,     # (1, 1, C*G, D)
     acc_ref,
     m_ref,
@@ -718,7 +731,7 @@ def _paged_chunk_quant_kernel(
     group: int,
     bits: int,
 ):
-    b = pl.program_id(0)
+    b, h = pl.program_id(0), pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -736,8 +749,8 @@ def _paged_chunk_quant_kernel(
         if bits == 4:
             kq = unpack_int4_splithalf(kq)
             vq = unpack_int4_splithalf(vq)
-        k = kq.astype(jnp.float32) * ks_ref[0]
-        v = vq.astype(jnp.float32) * vs_ref[0]
+        k = kq.astype(jnp.float32) * _head_scale(ks_ref, h)
+        v = vq.astype(jnp.float32) * _head_scale(vs_ref, h)
         live = _past_live(cur_ref[b], chunk, group, page_size, j)
         _flash_update(q, k, v, live, acc_ref, m_ref, l_ref, scale=scale)
 
@@ -793,7 +806,10 @@ def paged_flash_prefill_chunk_quant(
     page_spec = pl.BlockSpec(
         (1, None, page_size, dq), lambda bb, h, j, bt, cur: (bt[bb, j], h, 0, 0)
     )
-    scale_spec = pl.BlockSpec((1, None), lambda bb, h, j, bt, cur: (bt[bb, j], h))
+    # scales viewed (num_pages, 1, Hkv): the block is a page's whole row
+    scale_spec = pl.BlockSpec(
+        (1, 1, hkv), lambda bb, h, j, bt, cur: (bt[bb, j], 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, max_pages),
@@ -822,7 +838,8 @@ def paged_flash_prefill_chunk_quant(
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32), cursors.astype(jnp.int32),
-        qg, chunk_k, chunk_v, k_q, k_scale, v_q, v_scale,
+        qg, chunk_k, chunk_v, k_q, k_scale.reshape(num_pages, 1, hkv),
+        v_q, v_scale.reshape(num_pages, 1, hkv),
     )
     return jnp.swapaxes(out.reshape(b, hkv, c, group, d), 2, 3).reshape(b, hq, c, d)
 

@@ -34,10 +34,14 @@ def attn_specs(cfg, *, quant=None) -> Dict[str, TensorSpec]:
     d, h, hkv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.param_dtype
     s = {
-        "wq": TensorSpec((d, h, dh), ("embed", "heads", None), dtype=dt),
-        "wk": TensorSpec((d, hkv, dh), ("embed", "kv_heads", None), dtype=dt),
-        "wv": TensorSpec((d, hkv, dh), ("embed", "kv_heads", None), dtype=dt),
-        "wo": TensorSpec((h, dh, d), ("heads", None, "embed"), dtype=dt),
+        "wq": TensorSpec((d, h, dh), ("embed", "heads", None), dtype=dt,
+                         init="fan_in_to_heads"),
+        "wk": TensorSpec((d, hkv, dh), ("embed", "kv_heads", None), dtype=dt,
+                         init="fan_in_to_heads"),
+        "wv": TensorSpec((d, hkv, dh), ("embed", "kv_heads", None), dtype=dt,
+                         init="fan_in_to_heads"),
+        "wo": TensorSpec((h, dh, d), ("heads", None, "embed"), dtype=dt,
+                         init="fan_in_from_heads"),
     }
     if cfg.qkv_bias:
         s["bq"] = TensorSpec((h, dh), ("heads", None), dtype=jnp.float32, init="zeros")

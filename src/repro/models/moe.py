@@ -19,18 +19,6 @@ from repro.core.distributed import TensorSpec
 
 from .layers import NULL_SHARDER, Sharder
 
-# jax.shard_map (with check_vma) landed after 0.4.x; older releases ship it as
-# jax.experimental.shard_map.shard_map with the check_rep spelling of the same
-# knob. Resolve once so the EP path runs on both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
-
 def moe_specs(cfg, *, quant=None) -> Dict[str, TensorSpec]:
     from .layers import fit_quant
 
@@ -218,12 +206,12 @@ def apply_moe_ep(cfg, p, x: jax.Array, shard) -> Tuple[jax.Array, jax.Array]:
     xt = x.reshape(t, d)
     tok = tok_axes if len(tok_axes) > 1 else (tok_axes[0] if tok_axes else None)
     wspec3 = P("model", None, None)  # prefix-matches quantized {"q","scale"} leaves too
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(tok, None), P(None, None), wspec3, wspec3, wspec3),
         out_specs=(P(tok, None), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(xt, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out.reshape(b, s, d), aux
 

@@ -106,8 +106,13 @@ def test_quantized_store_roundtrip_at_tail_offsets(nblocks, delta, bits, data):
     before = np.array(acc.decay(bufs, span=span))
     for i in (0, span - 1):
         scale = float(np.array(bufs["scale"])[i // block])
-        v = data.draw(st.floats(-abs(scale) * acc.qmax, abs(scale) * acc.qmax,
-                                allow_nan=False, width=32))
+        # width=32 draws need float32-representable bounds: round the limit
+        # to float32, toward zero so every draw stays inside the block's range
+        lim = np.float32(abs(scale) * acc.qmax)
+        if lim > abs(scale) * acc.qmax:
+            lim = np.nextafter(lim, np.float32(0))
+        v = data.draw(st.floats(-float(lim), float(lim), allow_nan=False,
+                                width=32))
         b2 = acc.store(bufs, i, v)
         got = float(acc.access(b2, i))
         assert abs(got - v) <= max(scale, 1e-7) * 0.5 + 1e-5

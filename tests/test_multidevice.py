@@ -24,6 +24,7 @@ def test_shard_map_moe_matches_einsum_path():
     out = run_script(
         """
 import dataclasses, jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.models import get_config
 from repro.models.moe import moe_specs, apply_moe, apply_moe_ep
 from repro.models.layers import Sharder
@@ -32,7 +33,7 @@ from repro.core.distributed import tree_initialize
 
 cfg = dataclasses.replace(get_config("kimi-k2-1t-a32b", smoke=True), dtype="float32",
                           capacity_factor=8.0)  # no drops -> exact equality
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 rules = train_rules(cfg)
 p = tree_initialize(moe_specs(cfg), jax.random.key(0))
 x = jax.random.normal(jax.random.key(1), (8, 16, cfg.d_model))
@@ -77,6 +78,7 @@ def test_sharded_train_step_matches_single_device():
     out = run_script(
         """
 import dataclasses, jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.models import get_config, build_model
 from repro.models.layers import Sharder
 from repro.launch.sharding import train_rules
@@ -90,7 +92,8 @@ batch = {"tokens": jax.random.randint(jax.random.key(2), (8, 17), 0, cfg.vocab)}
 
 losses = {}
 for shard_it in (False, True):
-    mesh = jax.make_mesh((4, 2), ("data", "model")) if shard_it else None
+    mesh = (jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+            if shard_it else None)
     rules = train_rules(cfg) if shard_it else None
     step, ps, ss = make_train_step(model, AdamWConfig(lr=1e-3), mesh=mesh, rules=rules)
     params = tree_initialize(ps, jax.random.key(0))
