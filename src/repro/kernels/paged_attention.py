@@ -230,6 +230,7 @@ def paged_flash_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
@@ -471,6 +472,7 @@ def paged_flash_decode_quant(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_quant",
     )(
         block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
         qg, k_q, k_scale.reshape(num_pages, 1, hkv),
@@ -658,6 +660,7 @@ def paged_flash_prefill_chunk(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_chunk",
     )(
         block_tables.astype(jnp.int32), cursors.astype(jnp.int32),
         qg, chunk_k, chunk_v, k_pool, v_pool,
@@ -836,6 +839,7 @@ def paged_flash_prefill_chunk_quant(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_chunk_quant",
     )(
         block_tables.astype(jnp.int32), cursors.astype(jnp.int32),
         qg, chunk_k, chunk_v, k_q, k_scale.reshape(num_pages, 1, hkv),

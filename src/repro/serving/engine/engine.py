@@ -93,7 +93,7 @@ from repro.serving.step import (
     make_prefill,
     top_logprobs,
 )
-from repro.serving.telemetry import EngineTrace, MetricsRegistry
+from repro.serving.telemetry import EngineTrace, MetricsRegistry, gc_spans, span
 
 from .cache import PagedKVCache
 from .request import (
@@ -284,6 +284,13 @@ def _apply_tuning(config: EngineConfig, tuned) -> EngineConfig:
     return dataclasses.replace(config, **kw) if kw else config
 
 
+def _named_jit(name: str, fn, **kw):
+    """``jax.jit`` under a stable program name: a profile's XLA Modules line
+    shows ``jit_<name>``, whatever the factory called its function."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
+
+
 class ServeEngine:
     def __init__(self, model, params, config: EngineConfig = EngineConfig(),
                  mesh=None, rules=None):
@@ -345,7 +352,10 @@ class ServeEngine:
         self.registry = MetricsRegistry()
         self._h_step = self.registry.histogram("step_time_s")
         self._h_host = self.registry.histogram("host_overhead_s")
-        self._h_chunk = self.registry.histogram("chunk_time_s")
+        # wall between two ids fetches of consecutive decoding ticks, per
+        # token: the gap every decoding request sees between its tokens
+        self._h_gap = self.registry.histogram("decode_gap_s")
+        self._last_fetch_end: Optional[float] = None
         self._c_decode = self.registry.counter("decode_steps")
         self._c_fused = self.registry.counter("fused_steps")
         self._c_pf_computed = self.registry.counter("prefill_tokens_computed")
@@ -387,7 +397,8 @@ class ServeEngine:
         # persistent and only patched by allocator events (cache.device_state).
         step_donate = (1, 2, 4) + ((7,) if self._grammar_on else ())
         self._block_pages = config.decode_block_pages or None
-        self._step = jax.jit(
+        self._step = _named_jit(
+            "serve_decode_step",
             make_paged_serve_step(
                 model, mesh, rules, attn_impl=config.attn_impl,
                 kv_spec=self.cache.kv_spec, vocab=vocab,
@@ -400,7 +411,8 @@ class ServeEngine:
         # record_logits needs per-step rows on the host, so it forces K = 1.
         self._k = 1 if config.record_logits else max(1, int(config.multi_step))
         if self._k > 1:
-            self._multistep = jax.jit(
+            self._multistep = _named_jit(
+                "serve_decode_multistep",
                 make_paged_serve_multistep(
                     model, self._k, mesh, rules, attn_impl=config.attn_impl,
                     kv_spec=self.cache.kv_spec, vocab=vocab,
@@ -436,7 +448,8 @@ class ServeEngine:
                 table_size=config.spec_table_size, vocab=vocab,
                 hist_len=hist_len,
             )
-            self._spec_step = jax.jit(
+            self._spec_step = _named_jit(
+                "serve_spec_multistep",
                 make_paged_serve_spec_multistep(
                     model, self._spec_windows, self._proposer, mesh, rules,
                     attn_impl=config.attn_impl, kv_spec=self.cache.kv_spec,
@@ -516,7 +529,8 @@ class ServeEngine:
                 )
             # ONE compile serves every chunk of every prompt: cursor, valid
             # length and logits index are all traced
-            self._chunk_step = jax.jit(
+            self._chunk_step = _named_jit(
+                "serve_prefill_chunk",
                 make_chunked_prefill_step(
                     model, mesh, rules, attn_impl=config.attn_impl,
                     kv_spec=self.cache.kv_spec,
@@ -532,8 +546,8 @@ class ServeEngine:
         # per-token timing lives in the registry histograms (step_time_s:
         # device dispatch + execute + ids D2H, fused windows contributing
         # time / K per token; host_overhead_s: the wall the host loop adds
-        # around it; chunk_time_s: one entry per prefill chunk) — O(1) memory
-        # however long the run, metrics() snapshots their sketches
+        # around it; decode_gap_s: see _tokens_landed) — O(1) memory however
+        # long the run, metrics() snapshots their sketches
 
     # -- submission -------------------------------------------------------------
     def _register_grammar(self, dfa) -> int:
@@ -681,38 +695,43 @@ class ServeEngine:
             self._prefill_fns[padded_len] = fn
         return fn
 
-    def _admit_and_prefill(self, now: float) -> None:
-        tr = self.trace
-        # fresh branch-group siblings FORK the primary's pages once ITS
-        # prefill completes (_first_token), which also CLEARS their
-        # await_fork flag — snapshot the flag at admission so a sibling
-        # admitted alongside its primary isn't prefilled a second time in
-        # this same pass (that ghost prefill writes no KV — every page is
-        # shared — but would sample a duplicate first token)
-        to_prefill = [
+    def _admit_monolithic(self, now: float) -> list:
+        """Admit what fits; returns the (slot, state) pairs to prefill.
+        Fresh branch-group siblings FORK the primary's pages once ITS
+        prefill completes (_first_token), which also CLEARS their await_fork
+        flag — snapshot the flag at admission so a sibling admitted alongside
+        its primary isn't prefilled a second time in this same pass (that
+        ghost prefill writes no KV — every page is shared — but would sample
+        a duplicate first token)."""
+        return [
             (slot, state)
             for slot, state in self.scheduler.admit(self.queue, now)
             if not state.await_fork
         ]
+
+    def _prefill_monolithic(self, to_prefill) -> None:
+        tr = self.trace
         for slot, state in to_prefill:
             ctx = state.context
             padded = self.cache.pages_for(len(ctx)) * self.cache.page_size
             if tr is not None:
                 tr.instant("admit", slot, rid=state.request.rid, context=len(ctx))
-                tr.begin("prefill", slot, rid=state.request.rid, tokens=padded)
-            # right-pad to the page bucket so ONE compile serves every context
-            # length that rounds to it (preempted re-admissions arrive with
-            # arbitrary lengths); logits read at the true last position, the
-            # pad tail's KV lands in page slack that is masked or overwritten
-            tokens = jnp.asarray([list(ctx) + [0] * (padded - len(ctx))], jnp.int32)
-            logits, caches = self._prefill_fn(padded)(
-                self.params, tokens, last_index=jnp.int32(len(ctx) - 1)
-            )
-            self.cache.write_prefill(slot, caches)
-            self.cache.set_len(slot, len(ctx))
-            self._c_pf_computed.inc(padded)
-            if tr is not None:
-                tr.end("prefill", slot)
+            with span("serve.prefill", tr, slot, rid=state.request.rid,
+                      tokens=padded):
+                # right-pad to the page bucket so ONE compile serves every
+                # context length that rounds to it (preempted re-admissions
+                # arrive with arbitrary lengths); logits read at the true last
+                # position, the pad tail's KV lands in page slack that is
+                # masked or overwritten
+                tokens = jnp.asarray(
+                    [list(ctx) + [0] * (padded - len(ctx))], jnp.int32
+                )
+                logits, caches = self._prefill_fn(padded)(
+                    self.params, tokens, last_index=jnp.int32(len(ctx) - 1)
+                )
+                self.cache.write_prefill(slot, caches)
+                self.cache.set_len(slot, len(ctx))
+                self._c_pf_computed.inc(padded)
             self._first_token(state, logits[0, 0])
 
     def _first_token(self, state: RequestState, logits_row) -> None:
@@ -734,9 +753,10 @@ class ServeEngine:
         grp = state.group
         if grp is not None and grp.mode == "beam":
             vals, ids = self._row_logprobs(logits_row)
-            grp.pending_rows[state.branch] = (
-                np.asarray(vals[0]), np.asarray(ids[0])
-            )
+            with span("serve.fetch", what="first_token", rid=state.request.rid):
+                grp.pending_rows[state.branch] = (
+                    np.asarray(vals[0]), np.asarray(ids[0])
+                )
             state.hold = True  # masked from decode until the joint selection
             if state.first_token_time is None:
                 state.first_token_time = time.perf_counter() - self._t0
@@ -761,9 +781,10 @@ class ServeEngine:
             )
         else:
             tok_dev, lp_dev = self._sample_row(logits_row, f, i)
-        tok = int(tok_dev)
+        with span("serve.fetch", what="first_token", rid=state.request.rid):
+            tok, lp = int(tok_dev), float(lp_dev)
         state.generated.append(tok)
-        state.cum_logprob += float(lp_dev)
+        state.cum_logprob += lp
         if state.grammar_state is not None:
             state.grammar_state = int(self._gtrans_host[state.grammar_state, tok])
         self._slots_stale = True  # the slot's next decode input is host-known
@@ -773,16 +794,19 @@ class ServeEngine:
             self._spec_stale.add(state.slot)
         if state.request.logprobs:
             vals, ids = self._row_logprobs(logits_row)
-            vals, ids = np.asarray(vals[0]), np.asarray(ids[0])
+            with span("serve.fetch", what="first_token", rid=state.request.rid):
+                vals, ids = np.asarray(vals[0]), np.asarray(ids[0])
             state.logprobs[len(state.generated) - 1] = [
                 (int(i_), float(v))
                 for i_, v in zip(ids[: state.request.logprobs],
                                  vals[: state.request.logprobs])
             ]
         if self._records(state):
+            with span("serve.fetch", what="first_token", rid=state.request.rid):
+                row = np.asarray(logits_row[: self.model.cfg.vocab], np.float32)
             self.logits_of.setdefault(state.request.rid, {})[
                 len(state.generated) - 1
-            ] = np.asarray(logits_row[: self.model.cfg.vocab], np.float32)
+            ] = row
         if state.first_token_time is None:
             state.first_token_time = time.perf_counter() - self._t0
         if grp is not None and state.branch == 0:
@@ -915,9 +939,11 @@ class ServeEngine:
         content lands), and the chunk cursor starts at the shared-prefix
         compute skip — the last whole-page boundary at or before the first
         token the adopted pages don't already cover (always leaving >= 1 token
-        to compute: the prompt's last position must produce logits)."""
+        to compute: the prompt's last position must produce logits).
+        Returns the admitted (slot, state) pairs."""
         ps = self.cache.page_size
-        for slot, state in self.scheduler.admit(self.queue, now, publish=False):
+        admitted = self.scheduler.admit(self.queue, now, publish=False)
+        for slot, state in admitted:
             if state.await_fork:
                 continue  # fresh sibling: forks at the primary's first token
             n_ctx = len(state.context)
@@ -933,6 +959,7 @@ class ServeEngine:
                     "admit", slot, rid=state.request.rid, context=n_ctx,
                     skip=skip,
                 )
+        return admitted
 
     def _prefill_chunks(self, now: float) -> None:
         """Advance PREFILLING slots by at most one chunk each, within the
@@ -986,36 +1013,29 @@ class ServeEngine:
             while bucket < c_real:
                 bucket *= 2
             bucket = min(bucket, self._chunk_tokens)
-            # the chunk's tokens, zero-padded through the page bucket exactly as
-            # a monolithic prefill pads — the last chunk COMPUTES the pad tail's
-            # KV so its final page is bit-compatible with the monolithic page
-            # (and with the prefix index's purity law)
-            padded_ctx = list(ctx) + [0] * (padded - n_ctx)
-            toks = padded_ctx[cursor : cursor + c_real]
-            toks += [0] * (bucket - c_real)
-            read_row = self.cache.tables[slot : slot + 1]
-            write_row = self.cache.write_table_row(slot)[None, :]
-            tr = self.trace
-            if tr is not None:
-                tr.begin(
-                    "chunk", slot, rid=state.request.rid, cursor=cursor,
-                    tokens=c_real,
+            with span("serve.chunk", self.trace, slot, rid=state.request.rid,
+                      cursor=cursor, tokens=c_real, bucket=bucket):
+                # the chunk's tokens, zero-padded through the page bucket
+                # exactly as a monolithic prefill pads — the last chunk
+                # COMPUTES the pad tail's KV so its final page is
+                # bit-compatible with the monolithic page (and with the prefix
+                # index's purity law)
+                padded_ctx = list(ctx) + [0] * (padded - n_ctx)
+                toks = padded_ctx[cursor : cursor + c_real]
+                toks += [0] * (bucket - c_real)
+                read_row = self.cache.tables[slot : slot + 1]
+                write_row = self.cache.write_table_row(slot)[None, :]
+                logits, pools = self._chunk_step(
+                    self.params,
+                    self.cache.pools,
+                    jnp.asarray([toks], jnp.int32),
+                    jnp.asarray(read_row),
+                    jnp.asarray(write_row),
+                    jnp.asarray([cursor], jnp.int32),
+                    jnp.asarray([c_real], jnp.int32),
+                    jnp.asarray([min(n_ctx - 1 - cursor, c_real - 1)], jnp.int32),
                 )
-            t0 = time.perf_counter()
-            logits, pools = self._chunk_step(
-                self.params,
-                self.cache.pools,
-                jnp.asarray([toks], jnp.int32),
-                jnp.asarray(read_row),
-                jnp.asarray(write_row),
-                jnp.asarray([cursor], jnp.int32),
-                jnp.asarray([c_real], jnp.int32),
-                jnp.asarray([min(n_ctx - 1 - cursor, c_real - 1)], jnp.int32),
-            )
-            self.cache.pools = pools
-            self._h_chunk.observe(time.perf_counter() - t0)
-            if tr is not None:
-                tr.end("chunk", slot)
+                self.cache.pools = pools
             self._c_pf_computed.inc(c_real)
             if cursor + c_real >= n_ctx:  # this chunk covered the last position
                 state.chunk_cursor = None
@@ -1162,50 +1182,75 @@ class ServeEngine:
         owned pages and later appends overwrite them). The only bulk D2H is
         the (S, B, K+1) ids + committed-counts fetch."""
         wall0 = time.perf_counter()
-        self._sync_slot_state()
-        self._sync_spec_state(decoding)
-        tables, lens = self.cache.device_state()
+        with span("serve.sync"):
+            self._sync_slot_state()
+            self._sync_spec_state(decoding)
+            tables, lens = self.cache.device_state()
         kd = self._spec_k
         c = kd + 1
         tr = self.trace
-        if tr is not None:
-            tr.begin("spec_window", -1, windows=s, k=kd, batch=len(decoding))
-        want_lp = self._lp_k and any(
-            st.request.logprobs for st in decoding.values()
-        )
-        t0 = time.perf_counter()
-        out = self._spec_step(
-            self.params, self.cache.pools, self._tokens_dev, tables, lens,
-            self._slot_f32, self._slot_i32, self._hist_dev, self._table_dev,
-        )
-        toks, committed, last, new_lens, pools, lps = out[:6]
-        ids = np.asarray(toks)  # (S, B, C)
-        acc = np.asarray(committed)  # (S, B) tokens committed per window
-        lp_arr = np.asarray(lps)  # (S, B, C)
-        lp_vals = lp_ids = None
-        if want_lp:
-            lp_vals = np.asarray(out[8][0])  # (S, B, C, k)
-            lp_ids = np.asarray(out[8][1])
-        t_dev = time.perf_counter() - t0
-        self.cache.pools = pools
-        self.cache.adopt_lens_device(new_lens)
-        self._tokens_dev = last
-        self._hist_dev, self._table_dev = out[6], out[7]
-        per_win = t_dev / s  # one window = one model dispatch, like one step
-        for _ in range(s):
-            self._h_step.observe(per_win)
-        self._last_step_time = per_win
-        self._c_decode.inc(s)
-        self._c_fused.inc(s)
-        verdict = self._straggler.observe(per_win)
-        if verdict != "ok":
-            self._c_slow.inc()
+        with span("serve.spec_window", tr, windows=s, k=kd, batch=len(decoding)):
+            want_lp = self._lp_k and any(
+                st.request.logprobs for st in decoding.values()
+            )
+            t0 = time.perf_counter()
+            with span("serve.dispatch", k=s, batch=len(decoding)):
+                out = self._spec_step(
+                    self.params, self.cache.pools, self._tokens_dev, tables,
+                    lens, self._slot_f32, self._slot_i32, self._hist_dev,
+                    self._table_dev,
+                )
+            toks, committed, last, new_lens, pools, lps = out[:6]
+            with span("serve.fetch", what="ids"):
+                ids = np.asarray(toks)  # (S, B, C)
+                acc = np.asarray(committed)  # (S, B) tokens committed per window
+                lp_arr = np.asarray(lps)  # (S, B, C)
+                lp_vals = lp_ids = None
+                if want_lp:
+                    lp_vals = np.asarray(out[8][0])  # (S, B, C, k)
+                    lp_ids = np.asarray(out[8][1])
+            self._tokens_landed(s)
+            t_dev = time.perf_counter() - t0
+            self.cache.pools = pools
+            self.cache.adopt_lens_device(new_lens)
+            self._tokens_dev = last
+            self._hist_dev, self._table_dev = out[6], out[7]
+            per_win = t_dev / s  # one window = one model dispatch, like one step
+            self._observe_step(per_win, s)
+            self._c_fused.inc(s)
+            with span("serve.commit"):
+                win_acc, win_n = self._commit_spec(decoding, s, c, ids, acc,
+                                                   lp_arr, lp_vals, lp_ids)
+            mean = (win_acc / win_n) if win_n else 0.0
+            ema = self._spec_accept_ema
+            self._spec_accept_ema = mean if ema is None else 0.6 * ema + 0.4 * mean
+            if self.config.spec_backoff:
+                if self._spec_accept_ema < self.config.spec_accept_floor:
+                    self._spec_backoff_left = self._spec_backoff_len
+                    self._spec_backoff_len = min(
+                        self._spec_backoff_len * 2, 32 * self.config.spec_backoff
+                    )
+                    self._c_spec_backoffs.inc()
+                    if tr is not None:
+                        tr.instant(
+                            "spec_backoff", -1, ema=self._spec_accept_ema,
+                            floor=self.config.spec_accept_floor,
+                            dispatches=self._spec_backoff_left,
+                        )
+                else:
+                    # the stream pays again: next backoff starts from the base
+                    self._spec_backoff_len = int(self.config.spec_backoff)
             if tr is not None:
                 tr.instant(
-                    "slow_step", -1, verdict=verdict,
-                    step_ms=per_win * 1e3,
-                    ema_ms=(self._straggler.ema or 0.0) * 1e3,
+                    "spec_accept", -1, windows=win_n, accepted=win_acc,
+                    mean=mean,
                 )
+        wall = time.perf_counter() - wall0
+        self._h_host.observe((wall - t_dev) / s)
+
+    def _commit_spec(self, decoding, s, c, ids, acc, lp_arr, lp_vals, lp_ids):
+        """Commit each slot's accepted tokens of S speculative windows; returns
+        (tokens committed, slot-windows)."""
         win_acc = 0
         win_n = 0
         for i in range(s):
@@ -1240,33 +1285,37 @@ class ServeEngine:
                 # last committed token is the target's correction/bonus)
                 self._c_spec_hits.inc(min(take, max(a - 1, 0)))
                 self._c_spec_rollback.inc(c - a)
-        mean = (win_acc / win_n) if win_n else 0.0
-        ema = self._spec_accept_ema
-        self._spec_accept_ema = mean if ema is None else 0.6 * ema + 0.4 * mean
-        if self.config.spec_backoff:
-            if self._spec_accept_ema < self.config.spec_accept_floor:
-                self._spec_backoff_left = self._spec_backoff_len
-                self._spec_backoff_len = min(
-                    self._spec_backoff_len * 2, 32 * self.config.spec_backoff
+        return win_acc, win_n
+
+    def _tokens_landed(self, k: int) -> None:
+        """Feed ``decode_gap_s`` at the end of an ids fetch: when the tick
+        before this one also decoded, the wall since its fetch ended, over the
+        ``k`` steps this fetch brings, observed k times (as step_time_s is) —
+        the gap between two tokens that every decoding request sees. The
+        fetch that starts a decoding stretch observes nothing."""
+        end = time.perf_counter()
+        if self._last_fetch_end is not None:
+            gap = (end - self._last_fetch_end) / k
+            for _ in range(k):
+                self._h_gap.observe(gap)
+        self._last_fetch_end = end
+
+    def _observe_step(self, per_tok: float, k: int) -> None:
+        """Step-time histogram, the fused-horizon estimate, the decode counter
+        and the straggler verdict for one dispatch of ``k`` steps."""
+        for _ in range(k):
+            self._h_step.observe(per_tok)
+        self._last_step_time = per_tok
+        self._c_decode.inc(k)
+        verdict = self._straggler.observe(per_tok)
+        if verdict != "ok":
+            self._c_slow.inc()
+            if self.trace is not None:
+                self.trace.instant(
+                    "slow_step", -1, verdict=verdict,
+                    step_ms=per_tok * 1e3,
+                    ema_ms=(self._straggler.ema or 0.0) * 1e3,
                 )
-                self._c_spec_backoffs.inc()
-                if tr is not None:
-                    tr.instant(
-                        "spec_backoff", -1, ema=self._spec_accept_ema,
-                        floor=self.config.spec_accept_floor,
-                        dispatches=self._spec_backoff_left,
-                    )
-            else:
-                # the stream pays again: next backoff starts from the base
-                self._spec_backoff_len = int(self.config.spec_backoff)
-        if tr is not None:
-            tr.instant(
-                "spec_accept", -1, windows=win_n, accepted=win_acc,
-                mean=mean,
-            )
-            tr.end("spec_window", -1)
-        wall = time.perf_counter() - wall0
-        self._h_host.observe((wall - t_dev) / s)
 
     def _decode_once(self, now: float) -> None:
         """One device dispatch of the decode hot path: a single fused step, or
@@ -1288,13 +1337,10 @@ class ServeEngine:
             self._spec_stale.update(decoding)
         wall0 = time.perf_counter()
         k = self._fused_k(now)
-        self._sync_slot_state()
-        tables, lens = self.cache.device_state()
+        with span("serve.sync"):
+            self._sync_slot_state()
+            tables, lens = self.cache.device_state()
         record = self.config.record_logits
-        tr = self.trace
-        if tr is not None:
-            tr.begin("fused_window" if k > 1 else "decode", -1, k=k,
-                     batch=len(decoding))
         # requests riding the per-token fetch for logprobs (opt-in per request;
         # with nobody opted in the (B, k) pair is computed but never fetched) —
         # beam groups always ride it: the top-k pair IS their candidate set
@@ -1303,61 +1349,56 @@ class ServeEngine:
             or (st.group is not None and st.group.mode == "beam")
             for st in decoding.values()
         )
-        lp_vals = lp_ids = None
+        lp_vals = lp_ids = logits_rows = None
         g_args = (
             (self._gstate_dev, self._gmask_dev, self._gtrans_dev)
             if self._grammar_on else ()
         )
         lp_i = 6 if self._grammar_on else 5  # top-k pair's output index
-        t0 = time.perf_counter()
-        if k > 1:
-            out = self._multistep(
-                self.params, self.cache.pools, self._tokens_dev, tables, lens,
-                self._slot_f32, self._slot_i32, *g_args,
-            )
-            toks, last, new_lens, pools = out[:4]
-            ids = np.asarray(toks)  # (K, B) — the fused window's only D2H
-            lps = np.asarray(out[4])  # (K, B) chosen logprobs, same round
-            if want_lp:
-                lp_vals = np.asarray(out[lp_i][0])  # (K, B, k)
-                lp_ids = np.asarray(out[lp_i][1])
-            logits_rows = None
-            self._c_fused.inc(k)
-        else:
-            out = self._step(
-                self.params, self.cache.pools, self._tokens_dev, tables, lens,
-                self._slot_f32, self._slot_i32, *g_args,
-            )
-            last, logits, new_lens, pools = out[:4]
-            ids = np.asarray(last)[None]  # (1, B)
-            lps = np.asarray(out[4])[None]  # (1, B)
-            if want_lp:
-                lp_vals = np.asarray(out[lp_i][0])[None]  # (1, B, k)
-                lp_ids = np.asarray(out[lp_i][1])[None]
-            logits_rows = (
-                np.asarray(logits[:, : self.model.cfg.vocab], np.float32)
-                if record else None
-            )
-        if self._grammar_on:
-            self._gstate_dev = out[5]  # donated input's successor
-        t_dev = time.perf_counter() - t0
-        self.cache.pools = pools
-        self.cache.adopt_lens_device(new_lens)
-        self._tokens_dev = last
-        per_tok = t_dev / k
-        for _ in range(k):
-            self._h_step.observe(per_tok)
-        self._last_step_time = per_tok
-        self._c_decode.inc(k)
-        verdict = self._straggler.observe(per_tok)
-        if verdict != "ok":
-            self._c_slow.inc()
-            if tr is not None:
-                tr.instant(
-                    "slow_step", -1, verdict=verdict,
-                    step_ms=per_tok * 1e3,
-                    ema_ms=(self._straggler.ema or 0.0) * 1e3,
+        with span("serve.fused_window" if k > 1 else "serve.decode", self.trace,
+                  k=k, batch=len(decoding)):
+            t0 = time.perf_counter()
+            with span("serve.dispatch", k=k, batch=len(decoding)):
+                out = (self._multistep if k > 1 else self._step)(
+                    self.params, self.cache.pools, self._tokens_dev, tables,
+                    lens, self._slot_f32, self._slot_i32, *g_args,
                 )
+            if k > 1:
+                toks, last, new_lens, pools = out[:4]
+            else:
+                last, logits, new_lens, pools = out[:4]
+                toks = last
+            # (K, B) ids of a fused window, (B,) of one step: one round each
+            with span("serve.fetch", what="ids"):
+                ids = np.asarray(toks).reshape(k, -1)
+                lps = np.asarray(out[4]).reshape(k, -1)  # chosen logprobs
+                if want_lp:  # (K, B, top-k width)
+                    lp_vals = np.asarray(out[lp_i][0]).reshape(*ids.shape, -1)
+                    lp_ids = np.asarray(out[lp_i][1]).reshape(*ids.shape, -1)
+                if record and k == 1:
+                    logits_rows = np.asarray(
+                        logits[:, : self.model.cfg.vocab], np.float32
+                    )
+            self._tokens_landed(k)
+            if k > 1:
+                self._c_fused.inc(k)
+            if self._grammar_on:
+                self._gstate_dev = out[5]  # donated input's successor
+            t_dev = time.perf_counter() - t0
+            self.cache.pools = pools
+            self.cache.adopt_lens_device(new_lens)
+            self._tokens_dev = last
+            self._observe_step(t_dev / k, k)
+            with span("serve.commit"):
+                self._commit_decode(decoding, k, ids, lps, lp_vals, lp_ids,
+                                    logits_rows)
+        wall = time.perf_counter() - wall0
+        self._h_host.observe((wall - t_dev) / k)
+
+    def _commit_decode(self, decoding, k, ids, lps, lp_vals, lp_ids,
+                       logits_rows) -> None:
+        """Append each decoding slot's sampled tokens of ``k`` steps, advance
+        the host length mirror, and run the beam groups' joint selections."""
         beam_groups = []
         for i in range(k):
             for slot, state in decoding.items():
@@ -1400,120 +1441,142 @@ class ServeEngine:
             ]
             if all(st.branch in grp.pending_rows for st in started):
                 self._beam_advance(grp)
-        if tr is not None:
-            tr.end("fused_window" if k > 1 else "decode", -1)
-        wall = time.perf_counter() - wall0
-        self._h_host.observe((wall - t_dev) / k)
 
     def _sweep_finished(self) -> None:
-        for slot in list(self.scheduler.running):
-            state = self.scheduler.running[slot]
-            if state.done:
-                state.finish_time = time.perf_counter() - self._t0
-                reason = state.finished_reason()
-                if self.trace is not None:
-                    self.trace.instant(
-                        "finish", slot, rid=state.request.rid, reason=reason,
-                        generated=len(state.generated), branch=state.branch,
-                    )
-                # session retention: demote a cleanly-finished request's pages
-                # to the host tier with an eviction deadline, so a follow-up
-                # sharing this context prefetches instead of re-prefilling
-                if (self.cache.tier is not None
-                        and self.config.retain_finished_s > 0
-                        and state.error is None):
-                    self.cache.demote_slot(
-                        slot, state.hash_chain(self.cache.page_size),
-                        retain_s=self.config.retain_finished_s,
-                    )
-                # freeing this branch's pages decrefs — never frees — the
-                # pages its still-running siblings alias (cache.free_slot),
-                # so one branch's EOS neither stalls nor corrupts the rest
-                self.scheduler.finish(slot)
-                grp = state.group
-                if grp is None:
-                    self.results[state.request.rid] = state
-                elif grp.all_done and state.request.rid not in self.results:
-                    # the group completes as a UNIT: results carry the primary,
-                    # whose .sequences ranks/collects every branch
-                    grp.primary.finish_time = state.finish_time
-                    self.results[state.request.rid] = grp.primary
+        with span("serve.commit"):
+            for slot in list(self.scheduler.running):
+                state = self.scheduler.running[slot]
+                if state.done:
+                    self._retire(slot, state)
+
+    def _retire(self, slot: int, state: RequestState) -> None:
+        """Stamp a finished request, free its slot and record its result."""
+        state.finish_time = time.perf_counter() - self._t0
+        reason = state.finished_reason()
+        if self.trace is not None:
+            self.trace.instant(
+                "finish", slot, rid=state.request.rid, reason=reason,
+                generated=len(state.generated), branch=state.branch,
+            )
+        # session retention: demote a cleanly-finished request's pages
+        # to the host tier with an eviction deadline, so a follow-up
+        # sharing this context prefetches instead of re-prefilling
+        if (self.cache.tier is not None
+                and self.config.retain_finished_s > 0
+                and state.error is None):
+            self.cache.demote_slot(
+                slot, state.hash_chain(self.cache.page_size),
+                retain_s=self.config.retain_finished_s,
+            )
+        # freeing this branch's pages decrefs — never frees — the
+        # pages its still-running siblings alias (cache.free_slot),
+        # so one branch's EOS neither stalls nor corrupts the rest
+        self.scheduler.finish(slot)
+        grp = state.group
+        if grp is None:
+            self.results[state.request.rid] = state
+        elif grp.all_done and state.request.rid not in self.results:
+            # the group completes as a UNIT: results carry the primary,
+            # whose .sequences ranks/collects every branch
+            grp.primary.finish_time = state.finish_time
+            self.results[state.request.rid] = grp.primary
 
     # -- main loop ----------------------------------------------------------------
     def run(self, requests: Optional[Sequence[Request]] = None) -> Dict[int, RequestState]:
         """Serve until every submitted request completes; returns rid -> state.
         A request the pool can never hold (Scheduler.impossible) is FAILED —
         returned with .error set and empty .generated — instead of wedging the
-        queue; everything behind it keeps serving."""
+        queue; everything behind it keeps serving. Each iteration is one
+        ``serve.tick`` span; waiting for the next arrival lies outside it."""
         if requests is not None:
             self.submit_all(requests)
         self._pending.sort(key=lambda s: s.request.arrival_time)
-        chunked = self.config.chunked_prefill
         self._t0 = time.perf_counter()
-        while self._pending or self.queue or self.scheduler.running:
-            now = time.perf_counter() - self._t0
-            if self.cache.tier is not None:
-                self.cache.tier.begin_step()
-            # broken twins: a slot whose twin donor died before covering its
-            # adopted pages holds garbage — preempt it back to the queue for a
-            # clean re-admit (its pages never demote; they were never written)
-            for slot in self.cache.take_broken():
-                if slot in self.scheduler.running:
-                    self.scheduler.preempt_slot(slot, self.queue)
-            while self._pending and self._pending[0].request.arrival_time <= now:
-                state = self._pending.pop(0)
-                if self.trace is not None:
-                    self.trace.instant("enqueue", rid=state.request.rid)
-                self.queue.push(state)
-            for state in self.scheduler.reject_impossible(self.queue):
-                state.finish_time = time.perf_counter() - self._t0
-                # a rejected request can never resume: drop any host-tier
-                # residency its context holds (no orphaned host pages)
-                if self.cache.tier is not None:
-                    self.cache.release_host(
-                        state.hash_chain(self.cache.page_size)
-                    )
-                if state.group is not None:
-                    for st in state.group.branches:
-                        if st.finish_reason is None:  # keep earlier finishes
-                            st.error = state.error
-                            st.finish_reason = FINISH_ERROR
-                else:
-                    state.finish_reason = FINISH_ERROR
-                self.results[state.request.rid] = state
-            if chunked:
-                self._admit_chunked(now)
-                self._prefill_chunks(now)
-            else:
-                self._admit_and_prefill(now)
-            self._sweep_finished()  # a request can complete at prefill time
-            running = self.scheduler.running
-            if any(st.phase == DECODING for st in running.values()):
-                for slot in sorted(running):
-                    if slot in running and running[slot].phase == DECODING:
-                        self.scheduler.ensure_decode_page(slot, self.queue)
-                self._decode_once(now)
-                self._sweep_finished()
-            elif running:
-                pass  # only PREFILLING slots: next mixed step continues chunking
-            elif self._pending and not self.queue:
-                time.sleep(
-                    min(max(self._pending[0].request.arrival_time - now, 0.0), 0.01)
-                )
-            elif self.queue:
-                # nothing running, nothing arriving, head request not admitted:
-                # the whole (free) pool cannot hold its unshared pages — this
-                # can never resolve (with nothing running, no donor pages will
-                # ever join the prefix index). reject_impossible already failed
-                # requests too big for the pool, so this is the safety net for
-                # allocator states it cannot see.
-                head = self.queue.peek()
-                raise RuntimeError(
-                    f"request {head.request.rid} needs "
-                    f"{self.cache.new_pages_needed(head.context)} new pages but only "
-                    f"{self.cache.num_free} exist — raise num_pages"
-                )
+        self._last_fetch_end = None
+        with gc_spans():
+            while self._pending or self.queue or self.scheduler.running:
+                with span("serve.tick"):
+                    wait = self._tick()
+                if wait:
+                    time.sleep(wait)
         return self.results
+
+    def _tick(self) -> float:
+        """One iteration of the serving loop: arrivals, admission, prefill,
+        one decode dispatch, sweeping. Returns the seconds to sleep before the
+        next when nothing ran and the next arrival is not yet due, else 0."""
+        now = time.perf_counter() - self._t0
+        if self.cache.tier is not None:
+            self.cache.tier.begin_step()
+        # broken twins: a slot whose twin donor died before covering its
+        # adopted pages holds garbage — preempt it back to the queue for a
+        # clean re-admit (its pages never demote; they were never written)
+        for slot in self.cache.take_broken():
+            if slot in self.scheduler.running:
+                self.scheduler.preempt_slot(slot, self.queue)
+        chunked = self.config.chunked_prefill
+        with span("serve.admit") as sp:
+            self._enqueue_arrivals(now)
+            admitted = (self._admit_chunked(now) if chunked
+                        else self._admit_monolithic(now))
+            sp.set_metadata(admitted=len(admitted))
+        if chunked:
+            self._prefill_chunks(now)
+        else:
+            self._prefill_monolithic(admitted)
+        self._sweep_finished()  # a request can complete at prefill time
+        running = self.scheduler.running
+        if any(st.phase == DECODING for st in running.values()):
+            for slot in sorted(running):
+                if slot in running and running[slot].phase == DECODING:
+                    self.scheduler.ensure_decode_page(slot, self.queue)
+            self._decode_once(now)
+            self._sweep_finished()
+            return 0.0
+        self._last_fetch_end = None  # the decoding stretch, if any, ended
+        if running:
+            return 0.0  # only PREFILLING slots: next mixed step continues chunking
+        if self._pending and not self.queue:
+            return min(max(self._pending[0].request.arrival_time - now, 0.0), 0.01)
+        if self.queue:
+            # nothing running, nothing arriving, head request not admitted:
+            # the whole (free) pool cannot hold its unshared pages — this
+            # can never resolve (with nothing running, no donor pages will
+            # ever join the prefix index). reject_impossible already failed
+            # requests too big for the pool, so this is the safety net for
+            # allocator states it cannot see.
+            head = self.queue.peek()
+            raise RuntimeError(
+                f"request {head.request.rid} needs "
+                f"{self.cache.new_pages_needed(head.context)} new pages but only "
+                f"{self.cache.num_free} exist — raise num_pages"
+            )
+        return 0.0
+
+    def _enqueue_arrivals(self, now: float) -> None:
+        """Queue every pending request due by ``now``, then fail the queue-head
+        requests the pool can never hold."""
+        while self._pending and self._pending[0].request.arrival_time <= now:
+            state = self._pending.pop(0)
+            if self.trace is not None:
+                self.trace.instant("enqueue", rid=state.request.rid)
+            self.queue.push(state)
+        for state in self.scheduler.reject_impossible(self.queue):
+            state.finish_time = time.perf_counter() - self._t0
+            # a rejected request can never resume: drop any host-tier
+            # residency its context holds (no orphaned host pages)
+            if self.cache.tier is not None:
+                self.cache.release_host(
+                    state.hash_chain(self.cache.page_size)
+                )
+            if state.group is not None:
+                for st in state.group.branches:
+                    if st.finish_reason is None:  # keep earlier finishes
+                        st.error = state.error
+                        st.finish_reason = FINISH_ERROR
+            else:
+                state.finish_reason = FINISH_ERROR
+            self.results[state.request.rid] = state
 
     def reset_metrics(self) -> None:
         """Drop finished-request records and timing state (benchmarks rehearse a
@@ -1564,6 +1627,12 @@ class ServeEngine:
         ttft = np.array(
             [s.first_token_time - s.request.arrival_time for s in states]
         )
+        # TTFT split at the first admission: time queued, then prefill (the
+        # chunks, spread over ticks, and the first token's sampling)
+        queue_wait = np.array(
+            [s.admit_time - s.request.arrival_time for s in states]
+        )
+        prefill = np.array([s.first_token_time - s.admit_time for s in states])
         # decode work done: a branch group's primary stands for the whole
         # group in results, so count every branch's tokens, not just its own
         n_tok = sum(
@@ -1614,12 +1683,17 @@ class ServeEngine:
             # speculative bench gates on without prefill/scheduler noise
             "decode_ms_total": self._h_step.total * 1e3,
             "host_overhead_ms_p50": self._h_host.percentile(50) * 1e3,
-            "chunk_ms_p50": self._h_chunk.percentile(50) * 1e3,
+            "decode_gap_ms_p50": self._h_gap.percentile(50) * 1e3,
+            "decode_gap_ms_p98": self._h_gap.percentile(98) * 1e3,
             "latency_s_p50": float(np.percentile(e2e, 50)),
             "latency_s_p99": float(np.percentile(e2e, 99)),
             "ttft_s_p50": float(np.percentile(ttft, 50)),
             "ttft_s_p95": float(np.percentile(ttft, 95)),
             "ttft_s_p99": float(np.percentile(ttft, 99)),
+            "queue_wait_s_p50": float(np.percentile(queue_wait, 50)),
+            "queue_wait_s_p85": float(np.percentile(queue_wait, 85)),
+            "prefill_s_p50": float(np.percentile(prefill, 50)),
+            "prefill_s_p85": float(np.percentile(prefill, 85)),
             "preemptions": sum(s.n_preemptions for s in states),
             "slow_steps": self._c_slow.value,
             "prefill_tokens_computed": self._c_pf_computed.value,

@@ -147,7 +147,7 @@ class RequestState:
     # prefill completes (or always, in the monolithic engine). Reset by
     # release(): preemption is recompute-style, the cursor does not survive.
     chunk_cursor: Optional[int] = None
-    admit_time: Optional[float] = None
+    admit_time: Optional[float] = None  # first admission; re-admissions keep it
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     n_preemptions: int = 0

@@ -166,7 +166,8 @@ class Scheduler:
                             chain=self._chain_of(st), publish=publish,
                         )
                     st.slot = slot
-                    st.admit_time = now
+                    if st.admit_time is None:  # first admission only
+                        st.admit_time = now
                     self.running[slot] = st
                     admitted.append((slot, st))
                 continue
@@ -180,7 +181,8 @@ class Scheduler:
                 chain=self._chain_of(state), publish=publish,
             )
             state.slot = slot
-            state.admit_time = now
+            if state.admit_time is None:  # first admission only
+                state.admit_time = now
             self.running[slot] = state
             admitted.append((slot, state))
         return admitted

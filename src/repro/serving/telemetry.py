@@ -27,15 +27,26 @@ width (~7.5% at the default 32 buckets/decade — the tolerance the tests pin).
 ``validate_chrome_trace`` is the schema checker CI and the tests share: every
 event carries the required keys, timestamps are sorted, and B/E duration
 events pair up stack-wise per track.
+
+**span** — the one span call of the serving loop. Every span is a
+``jax.profiler.TraceAnnotation``, so it lands in the profiler's host plane on
+the device trace's clock whenever a profile runs (and costs about a
+microsecond when none does); with the ring on, the same call writes the
+ring's B/E pair. ``gc_spans`` adds ``serve.gc`` spans over the collector's
+pauses, which no span at a call site can catch.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import json
 import math
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 # ---------------------------------------------------------------------------------
@@ -337,6 +348,63 @@ class EngineTrace:
         from pathlib import Path
 
         Path(path).write_text(json.dumps(self.to_chrome()))
+
+
+class _RingSpan:
+    """A profiler span that also writes the ring's B/E pair."""
+
+    __slots__ = ("ann", "trace", "name", "track", "args")
+
+    def __init__(self, ann, trace: EngineTrace, name: str, track: int, args):
+        self.ann, self.trace, self.name = ann, trace, name
+        self.track, self.args = track, args
+
+    def __enter__(self):
+        self.trace.begin(self.name, self.track, **self.args)
+        return self.ann.__enter__()
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        self.trace.end(self.name, self.track)
+
+
+def span(name: str, trace: Optional[EngineTrace] = None,
+         track: int = SCHED_TRACK, **args):
+    """Context manager for one span of the serving loop, fed to every sink.
+
+    Always a ``TraceAnnotation(name, **args)``: recorded only while a profile
+    runs, in its host plane. With ``trace`` (the engine's ring, None when off)
+    also a B/E pair on ``track``, named as the ring's export has always named
+    it: ``name`` less its ``serve.`` prefix. Entering yields the annotation;
+    its ``set_metadata(**kw)`` adds args known only at the end (profile
+    only)."""
+    ann = TraceAnnotation(name, **args)
+    if trace is None:
+        return ann
+    return _RingSpan(ann, trace, name.removeprefix("serve."), track, args)
+
+
+@contextlib.contextmanager
+def gc_spans() -> Iterator[None]:
+    """While the block runs, each collector pause is a ``serve.gc`` span
+    (arg ``generation``): a ``gc.callbacks`` start/stop pair. A collection
+    runs to its end on the thread that triggered it, so pairs never
+    interleave."""
+    open_: List[TraceAnnotation] = []
+
+    def on_gc(phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            ann = TraceAnnotation("serve.gc", generation=info["generation"])
+            ann.__enter__()
+            open_.append(ann)
+        elif open_:
+            open_.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(on_gc)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(on_gc)
 
 
 def validate_chrome_trace(trace: Dict[str, Any]) -> None:

@@ -363,7 +363,7 @@ def test_engine_quant_dense_view_matches_prefill_within_scale_bound(small_model)
     eng.submit(Request(rid=0, prompt=prompt, params=GenerationParams(max_new_tokens=1)))
     eng._t0 = 0.0
     eng.queue.push(eng._pending.pop())
-    eng._admit_and_prefill(0.0)
+    eng._prefill_monolithic(eng._admit_monolithic(0.0))
     layout = eng.cache.layout_for(0)
     assert layout.is_unique() and not layout.is_strided()
     k_paged, _ = eng.cache.dense_view(0)  # decoded through the accessor
@@ -391,7 +391,7 @@ def test_engine_cache_dense_view_matches_layout(small_model):
     eng.submit(Request(rid=0, prompt=prompt, params=GenerationParams(max_new_tokens=1)))
     eng._t0 = 0.0
     eng.queue.push(eng._pending.pop())
-    eng._admit_and_prefill(0.0)
+    eng._prefill_monolithic(eng._admit_monolithic(0.0))
     layout = eng.cache.layout_for(0)
     assert layout.is_unique() and not layout.is_contiguous() and not layout.is_strided()
     k_paged, _ = eng.cache.dense_view(0)

@@ -10,7 +10,9 @@ the engine's own metrics counters — two independent observers of the same
 execution must agree.
 """
 import dataclasses
+import gc
 import json
+from typing import Dict, NamedTuple
 
 import jax
 import numpy as np
@@ -280,14 +282,56 @@ def test_preemption_run_trace_is_valid_and_matches_metrics(small_model, tmp_path
     assert all(results[r].error is None for r in results)
 
 
-def test_chunked_run_traces_chunk_spans(small_model):
+class Span(NamedTuple):
+    name: str
+    start: int  # ns, the profile's clock
+    end: int
+    args: Dict[str, object]
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; returns (its result, the ``serve.*``
+    spans of the profile's host plane in start order)."""
+    from jax.profiler import ProfileData, ProfileOptions
+
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0  # the program's spans, not every Python call
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    spans = [
+        Span(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, {k: v for k, v in ev.stats})
+        for plane in data.planes if plane.name.startswith("/host")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("serve.")
+    ]
+    return out, sorted(spans, key=lambda sp: sp.start)
+
+
+def _named(spans, *names):
+    return [sp for sp in spans if sp.name in names]
+
+
+def _inside(inner, outers) -> bool:
+    return any(o.start <= inner.start and inner.end <= o.end for o in outers)
+
+
+SERVE_SPANS = ("serve.tick", "serve.admit", "serve.chunk", "serve.sync",
+               "serve.dispatch", "serve.fetch", "serve.commit", "serve.gc")
+
+
+def test_chunked_run_traces_chunk_spans(small_model, tmp_path):
     cfg, model, params = small_model
     eng = ServeEngine(model, params, EngineConfig(
         num_pages=32, page_size=4, max_batch=2, max_pages_per_seq=16,
         chunked_prefill=True, chunk_tokens=8, trace=True,
     ))
     rng = np.random.default_rng(5)
-    eng.run([
+    _, spans = _profiled(tmp_path, lambda: eng.run([
         Request(
                 rid=0,
                 prompt=rng.integers(0, cfg.vocab, size=30).tolist(),
@@ -298,12 +342,133 @@ def test_chunked_run_traces_chunk_spans(small_model):
                 prompt=rng.integers(0, cfg.vocab, size=6).tolist(),
                 params=GenerationParams(max_new_tokens=4),
             ),
-    ])
+    ]))
     tr = eng.trace
     assert tr.count("chunk", ph="B") >= 2  # the 30-token prompt needs several
     assert tr.count("chunk", ph="B") == tr.count("chunk", ph="E")
     validate_chrome_trace(tr.to_chrome())
-    assert eng.metrics()["chunk_ms_p50"] > 0
+    # each chunk is one serve.chunk span on the profiler's clock, the ring's
+    # B/E pair written by the same call
+    chunks = _named(spans, "serve.chunk")
+    assert len(chunks) == tr.count("chunk", ph="B")
+    assert all(sp.end > sp.start for sp in chunks)
+    assert sum(sp.args["tokens"] for sp in chunks) == (
+        eng.metrics()["prefill_tokens_computed"])
+
+
+def test_serving_loop_spans_land_in_the_profilers_host_plane(small_model, tmp_path):
+    """A chunked run under the profiler records every span of the serving
+    loop, with its args as event stats: fetches and chunks nest inside a
+    tick, and with the ring on its B/E counts match the spans'."""
+    cfg, model, params = small_model
+    eng = ServeEngine(model, params, EngineConfig(
+        num_pages=32, page_size=4, max_batch=2, max_pages_per_seq=16,
+        chunked_prefill=True, chunk_tokens=8, trace=True,
+    ))
+    sweep = eng._sweep_finished
+
+    def sweep_and_collect():  # a collector pause inside the serving loop
+        gc.collect()
+        sweep()
+
+    eng._sweep_finished = sweep_and_collect
+    rng = np.random.default_rng(6)
+    _, spans = _profiled(tmp_path, lambda: eng.run([Request(
+            rid=i,
+            prompt=rng.integers(0, cfg.vocab, size=n).tolist(),
+            params=GenerationParams(max_new_tokens=4),
+        ) for i, n in enumerate((30, 6, 9))]))
+    assert {sp.name for sp in spans} >= set(SERVE_SPANS)
+    ticks = _named(spans, "serve.tick")
+    for sp in _named(spans, "serve.fetch", "serve.chunk", "serve.dispatch"):
+        assert _inside(sp, ticks), sp
+    fetches = _named(spans, "serve.fetch")
+    assert {sp.args["what"] for sp in fetches} == {"ids", "first_token"}
+    assert all("rid" in sp.args for sp in fetches if sp.args["what"] == "first_token")
+    assert all({"rid", "tokens", "bucket"} <= set(sp.args)
+               for sp in _named(spans, "serve.chunk"))
+    assert sum(sp.args["admitted"] for sp in _named(spans, "serve.admit")) == 3
+    assert all("generation" in sp.args for sp in _named(spans, "serve.gc"))
+    # the ring's pairs come from the same span calls
+    tr = eng.trace
+    assert tr.count("chunk", ph="B") == len(_named(spans, "serve.chunk"))
+    windows = _named(spans, "serve.decode", "serve.fused_window")
+    assert tr.count("decode", ph="B") + tr.count("fused_window", ph="B") == (
+        len(windows)) == len(_named(spans, "serve.dispatch"))
+    validate_chrome_trace(tr.to_chrome())
+
+
+def test_queue_wait_plus_prefill_is_ttft_and_first_admission_is_kept(small_model):
+    """TTFT splits at the first admission into queue wait and prefill, from
+    the stamps alone; a re-admission after preemption keeps that stamp."""
+    cfg, model, params = small_model
+    eng = ServeEngine(model, params, EngineConfig(
+        num_pages=10, page_size=4, max_batch=3, max_pages_per_seq=6,
+    ))
+    first_admit = {}
+    admit = eng.scheduler.admit
+
+    def spy(queue, now, **kw):
+        out = admit(queue, now, **kw)
+        for _, st in out:
+            first_admit.setdefault(st.request.rid, now)
+        return out
+
+    eng.scheduler.admit = spy
+    rng = np.random.default_rng(3)
+    results = eng.run([Request(
+            rid=i,
+            prompt=rng.integers(0, cfg.vocab, size=8).tolist(),
+            params=GenerationParams(max_new_tokens=10),
+        ) for i in range(3)])
+    m = eng.metrics()
+    assert m["preemptions"] >= 1  # the pool is sized to make this certain
+    states = list(results.values())
+    assert any(st.n_preemptions for st in states)
+    for st in states:
+        assert st.admit_time == first_admit[st.request.rid]
+        queue_wait = st.admit_time - st.request.arrival_time
+        prefill = st.first_token_time - st.admit_time
+        assert queue_wait >= 0 and prefill > 0
+        assert queue_wait + prefill == pytest.approx(
+            st.first_token_time - st.request.arrival_time, rel=0, abs=1e-12)
+    waits = [st.admit_time - st.request.arrival_time for st in states]
+    prefills = [st.first_token_time - st.admit_time for st in states]
+    for q in (50, 85):
+        assert m[f"queue_wait_s_p{q}"] == pytest.approx(np.percentile(waits, q))
+        assert m[f"prefill_s_p{q}"] == pytest.approx(np.percentile(prefills, q))
+
+
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_decode_gap_counts_every_step_but_a_stretchs_first(
+        small_model, tmp_path, multi_step):
+    """``decode_gap_s`` holds decode_steps observations less the k of each
+    window that began a decoding stretch (its tick followed one that did not
+    decode): the arrivals are spaced so the engine idles between them."""
+    cfg, model, params = small_model
+    eng = ServeEngine(model, params, EngineConfig.sized_for(
+        8 + 16 + 1, page_size=8, max_batch=2, multi_step=multi_step,
+    ))
+    rng = np.random.default_rng(8)
+    make = lambda: [Request(
+            rid=i,
+            prompt=rng.integers(0, cfg.vocab, size=8).tolist(),
+            params=GenerationParams(max_new_tokens=16),
+            arrival_time=t,
+        ) for i, t in enumerate((0.0, 0.0, 1.0, 2.0))]
+    eng.run(make())  # compile outside the spaced run
+    eng.reset_metrics()
+    _, spans = _profiled(tmp_path, lambda: eng.run(make()))
+    ticks = _named(spans, "serve.tick")
+    dispatches = _named(spans, "serve.dispatch")
+    ks = [[d.args["k"] for d in dispatches if _inside(d, [t])] for t in ticks]
+    first_ks = [k[0] for prev, k in zip([[]] + ks, ks) if k and not prev]
+    m = eng.metrics()
+    assert len(first_ks) >= 3  # one stretch per spaced arrival
+    assert sum(sum(k) for k in ks) == m["decode_steps"]
+    assert eng.registry.histogram("decode_gap_s").count == (
+        m["decode_steps"] - sum(first_ks))
+    assert 0 < m["decode_gap_ms_p50"] <= m["decode_gap_ms_p98"]
 
 
 def test_fused_window_trace_k_sums_to_fused_steps(small_model):
