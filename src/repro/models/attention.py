@@ -334,6 +334,17 @@ def self_attention_decode(
     return y, {"k": ck, "v": cv}
 
 
+def _append_tokens(pool, tok, page, slot):
+    """Scatter one token per batch row into its (page, slot) of a
+    (num_pages, Hkv, ps, Dh) pool; tok: (B, Hkv, Dh), page/slot: (B,) int32.
+
+    Each (row, head) writes one (Dh) window. A (Hkv, Dh) window per row would
+    make XLA lay the pool out with the head axis inside the slot axis, and the
+    paged kernels' layout would then cost a copy of the whole pool."""
+    heads = jnp.arange(pool.shape[1])[None, :]
+    return pool.at[page[:, None], heads, slot[:, None], :].set(tok.astype(pool.dtype))
+
+
 def _quant_append(buf, tok, page, slot, spec):
     """Scatter one quantized token per batch row into its (page, slot).
 
@@ -348,7 +359,7 @@ def _quant_append(buf, tok, page, slot, spec):
     scale = jnp.where(fresh, spec.token_scale(tok), buf["scale"][page])  # (B, Hkv)
     qtok = spec.quantize_tokens(tok, scale)            # (B, Hkv, Dq)
     return {
-        "q": buf["q"].at[page, :, slot, :].set(qtok),
+        "q": _append_tokens(buf["q"], qtok, page, slot),
         "scale": buf["scale"].at[page].set(scale),
     }
 
@@ -403,8 +414,8 @@ def self_attention_decode_paged(
             bits=kv_spec.bits, block_pages=block_pages, impl=impl,
         )
     else:
-        ck = cache["k"].at[page, :, slot, :].set(k[:, :, 0, :].astype(cache["k"].dtype))
-        cv = cache["v"].at[page, :, slot, :].set(v[:, :, 0, :].astype(cache["v"].dtype))
+        ck = _append_tokens(cache["k"], k[:, :, 0, :], page, slot)
+        cv = _append_tokens(cache["v"], v[:, :, 0, :], page, slot)
         out = ops.paged_decode_attention(
             q, ck, cv, block_tables, pos + 1, block_pages=block_pages, impl=impl
         )
@@ -464,21 +475,23 @@ def self_attention_verify_paged(
             ck = _quant_append(ck, k[:, :, j, :], page, slot, kv_spec)
             cv = _quant_append(cv, v[:, :, j, :], page, slot, kv_spec)
         else:
-            ck = ck.at[page, :, slot, :].set(k[:, :, j, :].astype(ck.dtype))
-            cv = cv.at[page, :, slot, :].set(v[:, :, j, :].astype(cv.dtype))
+            ck = _append_tokens(ck, k[:, :, j, :], page, slot)
+            cv = _append_tokens(cv, v[:, :, j, :], page, slot)
     # gather the present back from the pool: draft rows must attend the bytes
     # a sequential decode would read (pool dtype / page-scale dequant), not
     # the fresh f32 projections — greedy exactness depends on it
-    pg = jnp.stack(pages, axis=1)  # (B, C)
-    sl = jnp.stack(slots, axis=1)
+    # one (Dh) window per (row, token, head), like the appends
+    pg = jnp.stack(pages, axis=1)[..., None]  # (B, C, 1)
+    sl = jnp.stack(slots, axis=1)[..., None]
+    heads = jnp.arange(k.shape[1])
     if kv_spec is not None:
-        ks = ck["scale"][pg]  # (B, C, Hkv)
-        vs = cv["scale"][pg]
-        k_pres = kv_spec.decode_pages(ck["q"][pg, :, sl, :][:, :, :, None, :], ks)[..., 0, :]
-        v_pres = kv_spec.decode_pages(cv["q"][pg, :, sl, :][:, :, :, None, :], vs)[..., 0, :]
+        ks = ck["scale"][pg, heads]  # (B, C, Hkv)
+        vs = cv["scale"][pg, heads]
+        k_pres = kv_spec.decode_pages(ck["q"][pg, heads, sl, :][:, :, :, None, :], ks)[..., 0, :]
+        v_pres = kv_spec.decode_pages(cv["q"][pg, heads, sl, :][:, :, :, None, :], vs)[..., 0, :]
     else:
-        k_pres = ck[pg, :, sl, :].astype(jnp.float32)  # (B, C, Hkv, Dh)
-        v_pres = cv[pg, :, sl, :].astype(jnp.float32)
+        k_pres = ck[pg, heads, sl, :].astype(jnp.float32)  # (B, C, Hkv, Dh)
+        v_pres = cv[pg, heads, sl, :].astype(jnp.float32)
     k_pres = jnp.swapaxes(k_pres, 1, 2)  # (B, Hkv, C, Dh)
     v_pres = jnp.swapaxes(v_pres, 1, 2)
     if kv_spec is not None:
@@ -497,21 +510,28 @@ def self_attention_verify_paged(
 def _scatter_chunk_pages(cache, kp, vp, dest, kv_spec):
     """Scatter whole chunk pages into the pool. kp/vp: (B, nP, Hkv, ps, Dh) page-
     factored chunk KV; dest: (B, nP) physical destinations (invalid entries
-    already routed to the null page 0). Quantized pools encode one fresh scale
+    already routed to the null page). Quantized pools encode one fresh scale
     per (page, head) from the page's own absmax — exactly pack_kv_pages_quant's
     law, so a chunk-written page is bit-compatible with a monolithic-prefill
-    one and the prefix index may dedupe across the two regimes."""
+    one and the prefix index may dedupe across the two regimes.
+
+    Quantized codes are written one (Dq) token row per (page, head, slot):
+    int4's packed rows are 64 bytes, and a whole-page window would make XLA
+    keep the pool with the slot axis innermost, so the kernels' layout would
+    cost a copy of the whole pool in every layer."""
     b, npg = dest.shape
     flat = dest.reshape(-1)
     if kv_spec is not None:
         kq, vq = kv_spec.encode_pages(kp), kv_spec.encode_pages(vp)
         hkv, ps, dq = kq["q"].shape[2:]
+        rows = (flat[:, None, None], jnp.arange(hkv)[None, :, None],
+                jnp.arange(ps)[None, None, :])
         ck = {
-            "q": cache["k"]["q"].at[flat].set(kq["q"].reshape(b * npg, hkv, ps, dq)),
+            "q": cache["k"]["q"].at[rows].set(kq["q"].reshape(b * npg, hkv, ps, dq)),
             "scale": cache["k"]["scale"].at[flat].set(kq["scale"].reshape(b * npg, hkv)),
         }
         cv = {
-            "q": cache["v"]["q"].at[flat].set(vq["q"].reshape(b * npg, hkv, ps, dq)),
+            "q": cache["v"]["q"].at[rows].set(vq["q"].reshape(b * npg, hkv, ps, dq)),
             "scale": cache["v"]["scale"].at[flat].set(vq["scale"].reshape(b * npg, hkv)),
         }
         return ck, cv
@@ -534,6 +554,7 @@ def self_attention_prefill_chunk_paged(
     shard: Sharder = NULL_SHARDER,
     impl: str = "auto",
     kv_spec=None,
+    null_page=0,
 ):
     """One prefill CHUNK against a paged KV pool — the mixed-step prefill half.
 
@@ -552,7 +573,9 @@ def self_attention_prefill_chunk_paged(
     (core/submdspan.py §chunk views), executed as: scatter the chunk's KV into
     its own pages, then attend Q rows against everything resident with causal
     masking across the chunk boundary. ``kv_spec`` swaps in the quantized
-    accessor exactly as in the decode path.
+    accessor exactly as in the decode path. ``null_page`` is where the
+    chunk-bucket pad pages land: the pool's own null page (``l·P`` when the
+    pool is a layer stack's flat page space, Model.decode_step_paged).
     """
     b, c, d = x.shape
     ps = cache["k"]["q"].shape[2] if kv_spec is not None else cache["k"].shape[2]
@@ -573,7 +596,7 @@ def self_attention_prefill_chunk_paged(
         write_tables, jnp.clip(logical, 0, max_pages - 1), axis=1
     )
     valid = jnp.arange(npg)[None, :] * ps < n_new[:, None]
-    dest = jnp.where(valid, gathered, 0)
+    dest = jnp.where(valid, gathered, null_page)
     ck, cv = _scatter_chunk_pages(cache, kp, vp, dest, kv_spec)
     # attention: past from the pool (positions < cursor), present from the
     # chunk's own f32 k/v — the scattered pages never feed back into their own

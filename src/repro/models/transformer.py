@@ -152,11 +152,12 @@ class DenseBlock:
         return x, cache
 
     def prefill_chunk_paged(self, cfg, p, x, cache, block_tables, write_tables,
-                            cursors, n_new, shard, impl: str = "auto", kv_spec=None):
+                            cursors, n_new, shard, impl: str = "auto", kv_spec=None,
+                            null_page=0):
         h = apply_norm(cfg, x, p["ln_attn"])
         y, cache = attn.self_attention_prefill_chunk_paged(
             cfg, p["attn"], h, cache, block_tables, write_tables, cursors, n_new,
-            shard=shard, impl=impl, kv_spec=kv_spec,
+            shard=shard, impl=impl, kv_spec=kv_spec, null_page=null_page,
         )
         x = x + y
         h = apply_norm(cfg, x, p["ln_mlp"])
@@ -221,11 +222,12 @@ class MoEBlock(DenseBlock):
         return x + y, cache
 
     def prefill_chunk_paged(self, cfg, p, x, cache, block_tables, write_tables,
-                            cursors, n_new, shard, impl: str = "auto", kv_spec=None):
+                            cursors, n_new, shard, impl: str = "auto", kv_spec=None,
+                            null_page=0):
         h = apply_norm(cfg, x, p["ln_attn"])
         y, cache = attn.self_attention_prefill_chunk_paged(
             cfg, p["attn"], h, cache, block_tables, write_tables, cursors, n_new,
-            shard=shard, impl=impl, kv_spec=kv_spec,
+            shard=shard, impl=impl, kv_spec=kv_spec, null_page=null_page,
         )
         x = x + y
         h = apply_norm(cfg, x, p["ln_moe"])
@@ -703,7 +705,19 @@ class Model:
         C = K+1 rows of [current token, draft] appended and scored per block
         via verify_paged, ``context_lens`` the per-row resident length
         (NOT page-aligned), ``active`` honored as in decode, and the lm_head
-        applied to ALL C rows — returns logits (B, C, Vp)."""
+        applied to ALL C rows — returns logits (B, C, Vp).
+
+        The layer scan writes the pool in place. Each stacked pool leaf
+        (L, P, ...) is viewed as ONE flat page space (L·P, ...) — a bitcast —
+        that rides the scan carry, and the indexing law is: layer ``l``'s page
+        ``j`` is flat page ``l·P + j``. Layer ``l`` therefore addresses the
+        flat pool through ``block_tables + l·P`` (and ``write_tables + l·P``),
+        so nulled entries land in its own null page ``l·P``; the kernels see
+        an ordinary (num_pages, Hkv, ps, Dh) pool. This is the layer extent
+        folded into the paged layout's codomain: no layer of the pool is
+        sliced out of a scanned input or restacked into a scanned output, so
+        the step never copies the pool. The caller sees the (L, P, ...)
+        pytree it passed in."""
         cfg = self.cfg
         chunk = tokens.ndim == 2 and not spec_verify
         if active is not None and not chunk:
@@ -715,32 +729,34 @@ class Model:
         new_caches = []
         for (kind, n), p, cache in zip(block_program(cfg), params["blocks"], caches):
             blk = KINDS[kind]
+            n_pages = jax.tree.leaves(cache)[0].shape[1]
+            flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), cache)
 
-            if chunk:
-                def body(xc, pc, _blk=blk):
-                    pl, cl = pc
-                    return _blk.prefill_chunk_paged(
-                        cfg, pl, xc, cl, block_tables, write_tables,
+            def body(carry, pl_layer, _blk=blk, _n_pages=n_pages):
+                xc, pool = carry
+                pl, layer = pl_layer
+                base = layer * _n_pages
+                tables = block_tables + base
+                if chunk:
+                    xc, pool = _blk.prefill_chunk_paged(
+                        cfg, pl, xc, pool, tables, write_tables + base,
                         context_lens, n_new, shard, impl=attn_impl,
-                        kv_spec=kv_spec,
+                        kv_spec=kv_spec, null_page=base,
                     )
-            elif spec_verify:
-                def body(xc, pc, _blk=blk):
-                    pl, cl = pc
-                    return _blk.verify_paged(
-                        cfg, pl, xc, cl, block_tables, context_lens, shard,
+                elif spec_verify:
+                    xc, pool = _blk.verify_paged(
+                        cfg, pl, xc, pool, tables, context_lens, shard,
                         impl=attn_impl, kv_spec=kv_spec,
                     )
-            else:
-                def body(xc, pc, _blk=blk):
-                    pl, cl = pc
-                    return _blk.decode_paged(
-                        cfg, pl, xc, cl, block_tables, context_lens, shard,
+                else:
+                    xc, pool = _blk.decode_paged(
+                        cfg, pl, xc, pool, tables, context_lens, shard,
                         impl=attn_impl, kv_spec=kv_spec, block_pages=block_pages,
                     )
+                return (xc, pool), None
 
-            x, cache = stack_scan(body, x, (p, cache))
-            new_caches.append(cache)
+            (x, flat), _ = stack_scan(body, (x, flat), (p, jnp.arange(n, dtype=jnp.int32)))
+            new_caches.append(jax.tree.map(lambda f, a: f.reshape(a.shape), flat, cache))
         x = apply_norm(cfg, x, params["final_norm"])
         if spec_verify:
             # every row of the verify window needs its logits: row j decides

@@ -598,3 +598,115 @@ def test_grown_context_fails_request_and_serves_the_rest(small_model):
     assert results[0].error is None and len(results[0].generated) == 3
     assert results[1].error is not None and "pool" in results[1].error
     assert eng.metrics()["failed"] == 1
+
+
+# =====================================================================================
+# the layer scan writes the pool in place: exact against per-layer slicing
+# =====================================================================================
+def _per_layer_step(model, params, caches, tokens, block_tables, context_lens, *,
+                    kv_spec=None, write_tables=None, n_new=None, last_index=None,
+                    active=None, spec_verify=False):
+    """decode_step_paged as it was before the flat pool: the stacked pool is a
+    scanned input and the new pool a scanned output, so each layer's pool is
+    sliced out, updated and restacked, and every layer addresses its own
+    pages 0..P-1 through the unshifted tables."""
+    from repro.models.layers import NULL_SHARDER, apply_embed, apply_lm_head, apply_norm
+    from repro.models.transformer import KINDS, block_program
+
+    cfg = model.cfg
+    chunk = tokens.ndim == 2 and not spec_verify
+    if active is not None and not chunk:
+        block_tables = jnp.where(active[:, None] > 0, block_tables, 0)
+        context_lens = jnp.where(active > 0, context_lens, 0)
+    x = apply_embed(params["embed"], tokens if tokens.ndim == 2 else tokens[:, None])
+    new_caches = []
+    for (kind, _), p, cache in zip(block_program(cfg), params["blocks"], caches):
+        blk = KINDS[kind]
+
+        def body(xc, pc, _blk=blk):
+            pl, cl = pc
+            if chunk:
+                return _blk.prefill_chunk_paged(
+                    cfg, pl, xc, cl, block_tables, write_tables, context_lens,
+                    n_new, NULL_SHARDER, kv_spec=kv_spec)
+            if spec_verify:
+                return _blk.verify_paged(cfg, pl, xc, cl, block_tables, context_lens,
+                                         NULL_SHARDER, kv_spec=kv_spec)
+            return _blk.decode_paged(cfg, pl, xc, cl, block_tables, context_lens,
+                                     NULL_SHARDER, kv_spec=kv_spec)
+
+        x, cache = jax.lax.scan(body, x, (p, cache))
+        new_caches.append(cache)
+    x = apply_norm(cfg, x, params["final_norm"])
+    if spec_verify:
+        return apply_lm_head(cfg, params["embed"], x), new_caches
+    if chunk:
+        x = jnp.take_along_axis(x, last_index[:, None, None], axis=1)
+    return apply_lm_head(cfg, params["embed"], x)[:, 0], new_caches
+
+
+def _random_pool(model, num_pages, page_size, kv_spec, seed):
+    """A pool of random K/V in every page, the null pages included, so that a
+    read or a write through a wrong page shows."""
+    pools = model.init_paged_cache(num_pages, page_size, kv_spec=kv_spec)
+    leaves, tree = jax.tree.flatten(pools)
+    keys = jax.random.split(jax.random.key(seed), len(leaves))
+    filled = []
+    for key, leaf in zip(keys, leaves):
+        if leaf.dtype == jnp.int8:
+            filled.append(jax.random.randint(key, leaf.shape, -100, 100, jnp.int8))
+        elif kv_spec is not None:  # per-(page, head) scales
+            filled.append(jax.random.uniform(key, leaf.shape, leaf.dtype, 0.01, 0.05))
+        else:
+            filled.append(jax.random.normal(key, leaf.shape, leaf.dtype))
+    return jax.tree.unflatten(tree, filled)
+
+
+@pytest.mark.parametrize("mode", ["decode", "chunk", "verify"])
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8", "int4"])
+def test_flat_pool_layer_scan_matches_per_layer_slicing(small_model, kv_dtype, mode):
+    """decode_step_paged, whose layer scan carries the pool as one flat page
+    space (layer l's page j is flat page l·P + j), gives bit-equal logits and
+    bit-equal pools to the per-layer slice / update / restack scan: decode
+    with an inactive row routed to the null page, a chunk whose write table
+    nulls an adopted shared-prefix page and whose bucket has a pad page, and
+    a speculative verify window that starts mid-page."""
+    from repro.serving.engine.kvquant import KV_DTYPES
+
+    cfg, model, params = small_model
+    kv_spec = KV_DTYPES[kv_dtype]
+    ps, num_pages = 8, 13
+    pools = _random_pool(model, num_pages, ps, kv_spec, seed=len(mode))
+    tables = jnp.asarray([[3, 7, 1, 9], [2, 4, 0, 0], [5, 11, 6, 12]], jnp.int32)
+    kw = dict(kv_spec=kv_spec)
+    if mode == "decode":
+        tokens = jnp.asarray([17, 230, 401], jnp.int32)
+        lens = jnp.asarray([20, 9, 8], jnp.int32)  # row 2 starts a fresh page
+        kw.update(active=jnp.asarray([1, 0, 1], jnp.int32))
+    elif mode == "chunk":
+        tokens = jax.random.randint(jax.random.key(3), (3, 2 * ps), 0, cfg.vocab)
+        lens = jnp.asarray([0, 8, 16], jnp.int32)  # page-aligned cursors
+        # row 0 adopted its first page from a donor: read, never written
+        write = tables.at[0, 0].set(0)
+        kw.update(write_tables=write, n_new=jnp.asarray([16, 8, 16], jnp.int32),
+                  last_index=jnp.asarray([15, 7, 15], jnp.int32))
+    else:
+        tokens = jax.random.randint(jax.random.key(4), (3, 4), 0, cfg.vocab)
+        lens = jnp.asarray([5, 13, 19], jnp.int32)  # drafts cross page ends
+        kw.update(active=jnp.asarray([1, 1, 0], jnp.int32), spec_verify=True)
+    want_logits, want_pools = jax.jit(
+        lambda c: _per_layer_step(model, params, c, tokens, tables, lens, **kw)
+    )(pools)
+    got_logits, got_pools = jax.jit(
+        lambda c: model.decode_step_paged(params, c, tokens, tables, lens, **kw)
+    )(pools)
+    np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+    assert jax.tree.structure(got_pools) == jax.tree.structure(want_pools)
+    for got, want, before in zip(jax.tree.leaves(got_pools), jax.tree.leaves(want_pools),
+                                 jax.tree.leaves(pools)):
+        assert got.shape == want.shape == before.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the step wrote something in every layer: the comparison is not vacuous
+    for got, before in zip(jax.tree.leaves(got_pools), jax.tree.leaves(pools)):
+        for layer in range(cfg.n_layers):
+            assert not np.array_equal(np.asarray(got[layer]), np.asarray(before[layer]))
