@@ -7,7 +7,8 @@ Two numbers, each beside its limit:
 - ``logit_gap``: over a sample of the served requests, drawn from the seed
   and always holding the one with the most tokens, the widest gap by which a
   served token's logit lies below the best logit of the float32 reference at
-  the same position (teacher-forced over the prompt and the served tokens).
+  the same position (teacher-forced over the prompt and the served tokens):
+  the reference of the configuration's architecture (``arch``).
   Greedy serving in bf16 picks the reference's best token or one within
   rounding of it; the limit sits between what sound runs read and what the
   fp8 control reads (``PERF.md``, section 2).
@@ -18,7 +19,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchmarks.chip import reference
+from benchmarks.chip import arch
 
 SAMPLE_SALT = 0x5A3F1E
 
@@ -46,9 +47,9 @@ def sample(records, cfg: Dict, seed: int) -> List[int]:
 def gaps(params, cfg: Dict, items, records, picked) -> np.ndarray:
     """Per sampled served token: the reference's best logit minus its logit
     of the served token."""
-    out = []
+    ref, out = arch.of(cfg), []
     for i in picked:
-        best, got, _, _ = reference.served_rows(
+        best, got, _, _ = ref.served_rows(
             params, cfg["model"], cfg["rms_norm_eps"], items[i].prompt,
             records[i].generated)
         out.append(best - got)
@@ -59,11 +60,10 @@ def control_gaps(params, cfg: Dict, prompt, served):
     """(program gaps, control gaps) at the same positions: the float32
     reference's best logit minus its logit of the served token, and of the
     token the fp8 reference puts first."""
-    m, eps = cfg["model"], cfg["rms_norm_eps"]
-    _, _, first8, _ = reference.served_rows(params, m, eps, prompt, served,
-                                            fp8=True)
-    best, got, _, (ctrl,) = reference.served_rows(params, m, eps, prompt,
-                                                  served, lookups=[first8])
+    ref, m, eps = arch.of(cfg), cfg["model"], cfg["rms_norm_eps"]
+    _, _, first8, _ = ref.served_rows(params, m, eps, prompt, served, fp8=True)
+    best, got, _, (ctrl,) = ref.served_rows(params, m, eps, prompt, served,
+                                            lookups=[first8])
     return best - got, best - ctrl
 
 

@@ -19,8 +19,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from benchmarks.chip import arch, weights  # noqa: E402
 from benchmarks.chip import engine_cell as ec  # noqa: E402
-from benchmarks.chip import weights  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -49,17 +49,18 @@ def main(argv=None) -> int:
                  num_pages=pool // a.page_size)
     model = ec.build_model(cfg)
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    tree = arch.of(cfg).shapes(m)
     params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                          jax.eval_shape(weights.make_init(m), jax.random.key(0)))
-    L, hkv, ps, dh = m["n_layers"], m["n_kv_heads"], e["page_size"], m["d_head"]
-    page = sds((L, e["num_pages"], hkv, ps, dh), jnp.bfloat16)
-    pools = [{"k": page, "v": page}]
+                          jax.eval_shape(weights.make_init(tree), jax.random.key(0)))
+    ps = e["page_size"]
+    pools = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                         ec.pool_shapes(model, e["num_pages"], ps))
     b, mp = e["max_batch"], e["max_pages_per_seq"]
     i32 = jnp.int32
     out = {"config": a.config, "page_size": ps, "max_pages_per_seq": mp,
            "num_pages": e["num_pages"],
-           "params_bytes": weights.param_bytes(m),
-           "pool_bytes": 2 * L * e["num_pages"] * hkv * ps * dh * 2}
+           "params_bytes": weights.param_bytes(tree),
+           "pool_bytes": ec.pool_bytes(model, e["num_pages"], ps)}
     step = jax.jit(make_paged_serve_step(model, attn_impl="pallas", vocab=m["vocab"]),
                    donate_argnums=(1, 2, 4))
     progs = {"decode": (step, (params, pools, sds((b,), i32), sds((b, mp), i32),

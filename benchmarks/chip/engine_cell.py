@@ -8,6 +8,8 @@ import sys
 from pathlib import Path
 from typing import Dict
 
+import jax
+
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 if str(ROOT / "src") not in sys.path:
@@ -36,6 +38,16 @@ def engine_config(cfg: Dict) -> EngineConfig:
         max_batch=e["max_batch"], max_pages_per_seq=e["max_pages_per_seq"],
         chunked_prefill=True,
     )
+
+
+def pool_shapes(model: Model, num_pages: int, page_size: int):
+    """The KV pools' shapes and dtypes, as the engine allocates them."""
+    return jax.eval_shape(lambda: model.init_paged_cache(num_pages, page_size))
+
+
+def pool_bytes(model: Model, num_pages: int, page_size: int) -> int:
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(pool_shapes(model, num_pages, page_size)))
 
 
 def build_engine(model: Model, params, econf: EngineConfig) -> ServeEngine:

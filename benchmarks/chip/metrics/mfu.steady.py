@@ -8,5 +8,6 @@ def read(run):
     t = run.trace
     if not t or not run.traced or t["busy_s"] <= 0:
         return None
-    flops = peaks.model_flops(run.model, [(a, b) for _, a, b in run.traced])
+    flops = peaks.model_flops(run.model, run.layers,
+                              [(a, b) for _, a, b in run.traced])
     return 100.0 * flops / (t["busy_s"] * peaks.peaks(run.device_kind)["flops_bf16"])

@@ -17,8 +17,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+from benchmarks.chip import arch, weights  # noqa: E402
 from benchmarks.chip import engine_cell as ec  # noqa: E402
-from benchmarks.chip import weights  # noqa: E402
 from benchmarks.chip.compile_cache import enable  # noqa: E402
 
 
@@ -43,7 +43,7 @@ def main(argv=None) -> int:
     dev = jax.devices()[0]
     print(f"device {dev.platform} {dev.device_kind}", flush=True)
     t0 = time.perf_counter()
-    params = weights.make_init(m)(weights.weight_key(0))
+    params = weights.make_init(arch.of(cfg).shapes(m))(weights.weight_key(0))
     jax.block_until_ready(params)
     print(f"weights {time.perf_counter() - t0:.1f} s", flush=True)
     model = ec.build_model(cfg)
@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(0)
     out = []
     for ps in [int(x) for x in a.page_sizes.split(",")]:
-        kv_page = 2 * m["n_layers"] * m["n_kv_heads"] * ps * m["d_head"] * 2
+        kv_page = ec.pool_bytes(model, 1, ps)
         cfg["engine"].update(
             page_size=ps, max_pages_per_seq=-(-a.ctx // ps) + 1,
             num_pages=int(a.pool_gb * 1e9 / kv_page) if not a.tiny else 512)
@@ -73,9 +73,8 @@ def main(argv=None) -> int:
         row = dict(page_size=ps, max_pages=cfg["engine"]["max_pages_per_seq"],
                    num_pages=cfg["engine"]["num_pages"], warm_s=warm, wall_s=wall,
                    **{k: mt[k] for k in ("step_ms_p50", "step_ms_p95",
-                                         "host_overhead_ms_p50", "chunk_ms_p50",
-                                         "decode_steps", "tokens_per_s",
-                                         "prefill_tokens_computed")})
+                                         "host_overhead_ms_p50", "decode_steps",
+                                         "tokens_per_s", "prefill_tokens_computed")})
         if ps == int(a.page_sizes.split(",")[0]):
             eng.reset_metrics()
             jax.profiler.start_trace(a.trace_dir)

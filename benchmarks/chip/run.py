@@ -5,10 +5,11 @@
 One process: check for the chip, draw weights and traffic from the seed, build
 the engine, warm it on a rehearsal trace whose token ids are disjoint from the
 window's, then serve the window's requests through ``ServeEngine.run``. After
-the window the engine is freed and the plain float32 reference
-(``reference.py``) re-reads a sample of the served tokens; ``correct`` holds
-when every request was served in full and no sampled token's logit lies more
-than the configuration's limit below the reference's best. With ``--trace 1``
+the window the engine is freed and the plain float32 reference of the
+configuration's architecture (``arch``) re-reads a sample of the served
+tokens; ``correct`` holds when every request was served in full and no
+sampled token's logit lies more than the configuration's limit below the
+reference's best. With ``--trace 1``
 a profiler trace of a sub-window gives the device metrics, and the per-layer
 metrics are printed instead of the end-to-end ones.
 
@@ -100,12 +101,12 @@ def build(cfg, mix, seed):
     then ``reset_metrics``. Returns (params, engine)."""
     import jax
 
+    from benchmarks.chip import arch, traffic, weights
     from benchmarks.chip import engine_cell as ec
-    from benchmarks.chip import traffic, weights
 
     m = cfg["model"]
     t0 = time.perf_counter()
-    params = weights.make_init(m)(weights.weight_key(seed))
+    params = weights.make_init(arch.of(cfg).shapes(m))(weights.weight_key(seed))
     jax.block_until_ready(params)
     t1 = time.perf_counter()
     engine = ec.build_engine(ec.build_model(cfg), params, ec.engine_config(cfg))
@@ -168,7 +169,7 @@ def execute(cell, seed, seconds, trace, names, *, cfg=None, mix=None) -> int:
     CPU)."""
     import jax
 
-    from benchmarks.chip import check, compile_cache, traffic
+    from benchmarks.chip import arch, check, compile_cache, traffic
     from benchmarks.chip import engine_cell as ec
     from benchmarks.chip import trace as tr
     from benchmarks.chip.window import Run
@@ -204,6 +205,7 @@ def execute(cell, seed, seconds, trace, names, *, cfg=None, mix=None) -> int:
                               sorted(records, key=lambda r: r.arrival_s) if r.ok),
           file=sys.stderr)
     run = Run(cell=cell, model=cfg["model"], engine=cfg["engine"],
+              layers=arch.of(cfg).layers(cfg["model"]),
               device_kind=dev.device_kind, seconds=seconds, setup_s=setup_s,
               records=records, engine_metrics=engine.metrics())
     if tracer:

@@ -2,12 +2,17 @@
 look for a chip, sound and with the timed path broken underneath, and the
 fp8 control against the same limit."""
 import json
+import sys
 import time
+import types
 
 import jax
+import numpy as np
 import pytest
 
-from benchmarks.chip import engine_cell as ec, reference, run, traffic
+from benchmarks.chip import arch, check, reference, run, traffic, weights
+from benchmarks.chip import engine_cell as ec
+from benchmarks.chip.arch import dense_gqa
 
 CELL = {"name": "tiny.chat", "config": "qwen2.5-3b", "traffic": "chat", "chips": 1}
 
@@ -127,3 +132,59 @@ def test_the_fp8_control_in_the_programs_place_is_not_correct(capsys, monkeypatc
     assert out["failed"] == 0
     assert out["correct"] is False
     assert out["checks"]["logit_gap"]["value"] > out["checks"]["logit_gap"]["limit"]
+
+
+def test_the_configurations_architecture_gives_weights_reference_and_counts(
+        capsys, monkeypatch):
+    """A module registered under a new name, a dense copy that notes its
+    calls, is what a run of a configuration naming it draws its weights
+    from, checks against, and prices the work with."""
+    calls = []
+    spy = types.ModuleType("benchmarks.chip.arch.spy")
+
+    def noted(name):
+        def call(*a, **k):
+            calls.append(name)
+            return getattr(dense_gqa, name)(*a, **k)
+        return call
+
+    for name in ("shapes", "served_rows", "layers"):
+        setattr(spy, name, noted(name))
+    known = arch.names()
+    monkeypatch.setattr(arch, "names", lambda: known + ["spy"])
+    monkeypatch.setitem(sys.modules, spy.__name__, spy)
+    monkeypatch.setattr(run, "reader", lambda name: lambda r: len(r.layers))
+    cfg, mix = tiny()
+    cfg["arch"] = "spy"
+    run.T_START = time.perf_counter()
+    assert run.execute(CELL, 2**33 + 3, 2.0, False, [("layers", "1")],
+                       cfg=cfg, mix=mix) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["correct"] is True
+    assert out["metrics"]["layers"]["value"] == cfg["model"]["n_layers"]
+    assert {"shapes", "served_rows", "layers"} <= set(calls)
+
+
+@pytest.mark.parametrize("fp8", [False, True])
+def test_the_reference_through_the_registry_is_the_dense_reference(fp8):
+    """``check`` reaches the reference through the registry; on the tiny
+    configuration it reads bit for bit what ``reference.py`` reads."""
+    cfg, _ = tiny()
+    m, eps = cfg["model"], cfg["rms_norm_eps"]
+    params = weights.make_init(arch.of(cfg).shapes(m))(weights.weight_key(7))
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(1, m["vocab"], 40).tolist()
+    served = rng.integers(1, m["vocab"], 12).tolist()
+    lookups = [rng.integers(1, m["vocab"], 12).tolist()]
+    got = arch.of(cfg).served_rows(params, m, eps, prompt, served, fp8=fp8,
+                                   lookups=lookups)
+    want = reference.served_rows(params, m, eps, prompt, served, fp8=fp8,
+                                 lookups=lookups)
+    for g, w in zip([*got[:3], *got[3]], [*want[:3], *want[3]]):
+        assert np.array_equal(g, w)
+    if not fp8:
+        best, tok, _, _ = want
+        items = [types.SimpleNamespace(prompt=prompt)]
+        records = [types.SimpleNamespace(generated=served)]
+        assert np.array_equal(check.gaps(params, cfg, items, records, [0]),
+                              best - tok)

@@ -6,6 +6,7 @@ import importlib.util
 from types import SimpleNamespace
 
 from benchmarks.chip import trace
+from benchmarks.chip.arch import dense_gqa
 from benchmarks.chip.window import Progress, positions_between
 
 HERE = Path(__file__).resolve().parent
@@ -13,16 +14,18 @@ FIXTURE = HERE / "fixtures" / "qwen_steps.json.gz"
 
 
 # the recorded configuration: Qwen2.5-3B at batch 64 and 128-token pages
-RUN = SimpleNamespace(model={"n_heads": 16, "n_kv_heads": 2, "d_head": 128},
+MODEL = dict(n_layers=36, d_model=2048, n_heads=16, n_kv_heads=2, d_head=128,
+             d_ff=11008)
+RUN = SimpleNamespace(model=MODEL, layers=dense_gqa.layers(MODEL),
                       engine={"max_batch": 64, "page_size": 128})
 
 
-def kernel_out(metric):
+def kernel_outs(metric):
     spec = importlib.util.spec_from_file_location(
         metric, HERE.parent / "metrics" / f"{metric}.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.kernel_out(RUN)
+    return mod.kernel_outs(RUN)
 
 
 def test_union_merges_overlaps_and_keeps_gaps():
@@ -47,7 +50,8 @@ def test_reduce_a_recorded_chip_trace():
     assert sum(r["ops"].values()) >= r["busy_s"] * 0.999
     # the chunk kernel, by its reader's match, and the decode kernel, by its
     # output (max_batch, Hkv, Hq / Hkv, head_dim): told apart by shape
-    assert trace.kernel_seconds(r, kernel_out("prefill_attn_roofline")) > 0
+    (chunk,) = kernel_outs("prefill_attn_roofline")  # one KV-head count
+    assert trace.kernel_seconds(r, chunk) > 0
     assert trace.kernel_seconds(r, "bf16[64,2,8,128]") > 0
     # the layer scans' while loops hold the other ops: not among the top
     assert not any(n.startswith("%while") for n, _ in r["top_ops"])

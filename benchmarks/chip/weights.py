@@ -1,15 +1,12 @@
-"""Random weights for a dense GQA decoder, drawn from the seed on the device.
+"""Random weights, drawn from the seed on the device.
 
-One jitted program draws every leaf in the dtype it is served in, so set-up
-pays one compile (cached across runs) and no per-leaf dispatch. The pytree has
-the layout the serving engine consumes (``Model.param_specs`` of the program:
-stacked layers under ``blocks[0]``); ``tests/test_weights.py`` pins that the
-shapes agree. The plain reference (``reference.py``) reads the same arrays.
-
-Scales keep a 36-layer random model well-conditioned: projections are normal
-with variance 1/fan-in, norm scales 1 + 0.1 N(0, 1), biases 0.1 N(0, 1), and
-the embedding and output head 3/sqrt(d_model), so the logits have a standard
-deviation near 3, as a trained model's do.
+The tree of leaves comes from the configuration's architecture module
+(``arch.of(cfg).shapes(m)``). One jitted program draws every leaf in the
+dtype it is served in, so set-up pays one compile (cached across runs) and no
+per-leaf dispatch. The tree has the layout the serving engine consumes
+(``Model.param_specs`` of the program); ``tests/test_weights.py`` pins that
+the shapes agree for every committed configuration. The architecture's plain
+reference reads the same arrays.
 """
 from __future__ import annotations
 
@@ -21,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 VOCAB_PAD = 256  # the program pads the vocabulary rows to this multiple
-LOGIT_STD = 3.0
 
 
 def padded_vocab(vocab: int) -> int:
@@ -31,41 +27,6 @@ def padded_vocab(vocab: int) -> int:
 def weight_key(seed: int) -> jax.Array:
     """A PRNG key from any whole-number seed (seeds may exceed 32 bits)."""
     return jax.random.key(int(np.random.default_rng(seed).integers(0, 2**31 - 1)))
-
-
-def shapes(m: Dict) -> Dict:
-    """Leaf name -> (shape, dtype, init std or 'norm'/'bias'), nested like
-    the program's parameter tree."""
-    d, f, L = m["d_model"], m["d_ff"], m["n_layers"]
-    h, hkv, dh = m["n_heads"], m["n_kv_heads"], m["d_head"]
-    vp = padded_vocab(m["vocab"])
-    wdt = m["dtype"]
-    head_std = LOGIT_STD / math.sqrt(d)
-    embed = {"embedding": ((vp, d), wdt, head_std)}
-    if not m["tie_embeddings"]:
-        embed["lm_head"] = ((d, vp), wdt, head_std)
-    attn = {
-        "wq": ((L, d, h, dh), wdt, 1 / math.sqrt(d)),
-        "wk": ((L, d, hkv, dh), wdt, 1 / math.sqrt(d)),
-        "wv": ((L, d, hkv, dh), wdt, 1 / math.sqrt(d)),
-        "wo": ((L, h, dh, d), wdt, 1 / math.sqrt(h * dh)),
-    }
-    if m["qkv_bias"]:
-        attn.update(bq=((L, h, dh), "float32", "bias"),
-                    bk=((L, hkv, dh), "float32", "bias"),
-                    bv=((L, hkv, dh), "float32", "bias"))
-    block = {
-        "ln_attn": ((L, d), "float32", "norm"),
-        "attn": attn,
-        "ln_mlp": ((L, d), "float32", "norm"),
-        "mlp": {
-            "w_gate": ((L, d, f), wdt, 1 / math.sqrt(d)),
-            "w_up": ((L, d, f), wdt, 1 / math.sqrt(d)),
-            "w_down": ((L, f, d), wdt, 1 / math.sqrt(f)),
-        },
-    }
-    return {"embed": embed, "blocks": [block],
-            "final_norm": ((d,), "float32", "norm")}
 
 
 def _is_leaf(x) -> bool:
@@ -84,9 +45,9 @@ def _draw(key, leaf):
     return z.astype(dtype)
 
 
-def make_init(m: Dict):
-    """A jitted ``key -> params`` for model keys ``m`` (one program)."""
-    tree = shapes(m)
+def make_init(tree: Dict):
+    """A jitted ``key -> params`` drawing the leaves of ``tree`` (one
+    program)."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_leaf)
 
     @jax.jit
@@ -98,14 +59,14 @@ def make_init(m: Dict):
     return init
 
 
-def param_bytes(m: Dict) -> int:
-    leaves = jax.tree.leaves(shapes(m), is_leaf=_is_leaf)
+def param_bytes(tree: Dict) -> int:
+    leaves = jax.tree.leaves(tree, is_leaf=_is_leaf)
     return sum(math.prod(s) * jnp.dtype(dt).itemsize for s, dt, _ in leaves)
 
 
-def param_count(m: Dict, *, padded: bool = False) -> int:
-    """Parameters of the model; the vocabulary's pad rows only if asked."""
-    tree = shapes(m)
+def param_count(m: Dict, tree: Dict, *, padded: bool = False) -> int:
+    """Parameters of ``tree``, the weights of model keys ``m``; the
+    vocabulary's pad rows only if asked."""
     n = sum(math.prod(s) for s, _, _ in jax.tree.leaves(tree, is_leaf=_is_leaf))
     if not padded:
         pad = padded_vocab(m["vocab"]) - m["vocab"]

@@ -9,6 +9,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
+from benchmarks.chip.peaks import Layer
+
 
 @dataclasses.dataclass(frozen=True)
 class Record:
@@ -56,6 +58,7 @@ class Run:
     cell: Dict
     model: Dict  # the configuration's model keys
     engine: Dict  # the configuration's engine sizing
+    layers: List[Layer]  # the work a token does in each layer (``arch``)
     device_kind: str
     seconds: float
     setup_s: float
