@@ -26,6 +26,7 @@ from repro.core.accessors import QuantizedAccessor
 from repro.core.distributed import dequantize_array
 from repro.core.layouts import LayoutLeft, LayoutRight
 
+from . import paged_attention as _paged_attention
 from . import ref
 from .common import use_interpret
 from .flash_attention import flash_attention as _flash_fwd
@@ -176,22 +177,65 @@ def effective_block_pages(block_pages, max_pages: int) -> int:
 
 def paged_decode_attention(
     q, k_pool, v_pool, block_tables, context_lens, *, scale=None,
-    block_pages=None, impl: str = "auto",
+    block_pages=None, window=None, impl: str = "auto",
 ):
     """One-token GQA decode against a LayoutPaged pool (num_pages, Hkv, ps, D);
     block_tables (B, max_pages) int32; context_lens (B,) int32 per-sequence.
     ``block_pages`` (pages per compute block; autotuned) is sanitized here via
-    effective_block_pages, so callers pass the tuned value verbatim."""
+    effective_block_pages, so callers pass the tuned value verbatim.
+    ``window``: a sliding-window layer reads only its row's window pages."""
     bp = effective_block_pages(block_pages, block_tables.shape[1])
     if _want_pallas(impl):
         return _paged_flash_decode(
             q, k_pool, v_pool, block_tables, context_lens, scale=scale,
-            block_pages=bp,
+            block_pages=bp, window=window,
         )
     return _paged_decode_jnp(
         q, k_pool, v_pool, block_tables, context_lens, scale=scale,
-        block_pages=bp if bp > 1 else None,
+        block_pages=bp if bp > 1 else None, window=window,
     )
+
+
+def _tile(n: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``n`` and is at most ``cap``
+    (``n`` itself when none does)."""
+    for t in range(cap - cap % 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def grouped_matmul(rows, w, group_sizes, *, layer=None, impl: str = "auto"):
+    """(M, K) rows sorted by group, times each group's (K, N) matrix ->
+    (M, N) float32; rows past the groups' sum are left undefined. ``w`` is
+    (G, K, N), or a stack (L, G, K, N) with ``layer`` (traced) picking one.
+
+    On the TPU: megablox's grouped matmul (Pallas), whose grid visits only
+    the row tiles of non-empty groups and whose index map takes each group's
+    weight tiles from ``w``; a stack is handed over whole, as L·G groups of
+    which only ``layer``'s are non-empty, so no layer's weights are copied
+    out of it. Elsewhere: ``lax.ragged_dot``."""
+    if not _want_pallas(impl):
+        if layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+        return jax.lax.ragged_dot(rows, w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    g = w.shape[-3]
+    if layer is not None:
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w.shape[0] * g,), jnp.int32), group_sizes, (layer * g,))
+        w = w.reshape((-1,) + w.shape[2:])
+    m, k, n = rows.shape[0], w.shape[1], w.shape[2]
+    tm = 16 if m <= 512 else 128  # decode: a few rows a group; chunks: ~64
+    pad = -m % tm
+    lhs = jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows
+    # the paged kernels' interpreter switch (one switch for the serving step)
+    out = gmm(lhs, w, group_sizes, preferred_element_type=jnp.float32,
+              tiling=(tm, _tile(k, 1152), _tile(n, 1152)),
+              interpret=_paged_attention.use_interpret())
+    return out[:m]
 
 
 def paged_decode_attention_quant(
@@ -217,20 +261,22 @@ def paged_decode_attention_quant(
 
 def paged_prefill_chunk_attention(
     q, chunk_k, chunk_v, k_pool, v_pool, block_tables, cursors, *,
-    scale=None, impl: str = "auto",
+    scale=None, window=None, impl: str = "auto",
 ):
     """Chunked-prefill GQA attention: a Q-chunk (B, Hq, C, D) against the
     resident PAST (pool positions < cursors[b], read through the block table)
     plus its own PRESENT (chunk_k/chunk_v, (B, Hkv, C, D) f32, intra-chunk
     causal) — one online softmax across both. The C == 1 case is
-    paged_decode_attention; this is the mixed-step prefill half."""
+    paged_decode_attention; this is the mixed-step prefill half. ``window``
+    limits each query to the keys within it of its position."""
     if _want_pallas(impl):
         return _paged_flash_chunk(
             q, chunk_k, chunk_v, k_pool, v_pool, block_tables, cursors,
-            scale=scale,
+            scale=scale, window=window,
         )
     return _paged_chunk_jnp(
-        q, chunk_k, chunk_v, k_pool, v_pool, block_tables, cursors, scale=scale
+        q, chunk_k, chunk_v, k_pool, v_pool, block_tables, cursors, scale=scale,
+        window=window,
     )
 
 

@@ -52,6 +52,23 @@ from .common import use_interpret
 NEG_INF = -1e30
 
 
+def window_pages(window: int, page_size: int) -> int:
+    """Pages that a row's last ``window`` keys can overlap: the page axis of
+    the windowed decode and chunk grids."""
+    return -(-window // page_size) + 1
+
+
+def _window_first(n, window: int, page_size: int):
+    """Logical page of key max(0, n - window): the first page a windowed
+    row of ``n`` keys reads (a chunk passes its cursor + 1)."""
+    return jnp.maximum(n - window, 0) // page_size
+
+
+def _window_tag(window):
+    """pallas_call keywords that tag a windowed kernel in the program text."""
+    return {} if window is None else {"metadata": {"window": str(window)}}
+
+
 # ---------------------------------------------------------------------------------
 # int4 nibble packing (split-half) + page dequantization
 # ---------------------------------------------------------------------------------
@@ -124,6 +141,7 @@ def _paged_decode_kernel(
     scale: float,
     page_size: int,
     block_pages: int,
+    window: int | None = None,
 ):
     b = pl.program_id(0)
     # the page loop is structured as (compute block jb) x (page-in-block ji):
@@ -141,9 +159,14 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     seq_len = len_ref[b]
+    if window is not None:
+        # grid step j visits the row's j-th window page (_window_first)
+        j = _window_first(seq_len, window, page_size) + j
     # absolute position of slot i in logical page j is j*page_size + i
     k_pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (g_sz, page_size), 1)
     live = k_pos < seq_len
+    if window is not None:
+        live = live & (k_pos >= seq_len - window)
 
     @pl.when(j * page_size < seq_len)
     def _body():
@@ -168,6 +191,7 @@ def paged_flash_decode(
     *,
     scale: float | None = None,
     block_pages: int = 1,
+    window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """One-token GQA decode against a paged KV pool.
@@ -187,6 +211,13 @@ def paged_flash_decode(
     cannot share one BlockSpec window); the knob exists so configurations
     tuned on the jnp twin — where it sets the real gather granularity — carry
     through this kernel's grid unchanged.
+
+    ``window`` (sliding-window layers): a row of length n attends to the keys
+    from max(0, n - window) to its own token, and the page axis of the grid
+    is ``window_pages(window, page_size)`` steps whatever ``max_pages`` is,
+    the index map starting at the row's first live page; ``block_pages`` is
+    then 1. The kernel is tagged ``{"window": ...}`` in the program's
+    ``kernel_metadata``, so a device trace tells it from the full one.
     """
     interpret = use_interpret() if interpret is None else interpret
     b, hq, tq, d = q.shape
@@ -194,7 +225,7 @@ def paged_flash_decode(
     assert tq == 1 and hq % hkv == 0
     group = hq // hkv
     max_pages = block_tables.shape[1]
-    bp = max(1, int(block_pages))
+    bp = max(1, int(block_pages)) if window is None else 1
     if max_pages % bp:
         raise ValueError(
             f"block_pages {bp} must divide max_pages {max_pages} "
@@ -204,19 +235,25 @@ def paged_flash_decode(
     qg = q.reshape(b, hkv, group, d)
 
     kern = functools.partial(
-        _paged_decode_kernel, scale=scale, page_size=page_size, block_pages=bp
+        _paged_decode_kernel, scale=scale, page_size=page_size, block_pages=bp,
+        window=window,
     )
+    if window is None:
+        n_steps = max_pages // bp
+        # the LayoutPaged indirection: logical page jb*bp + ji of sequence
+        # bb DMAs physical page block_tables[bb, jb*bp + ji]
+        page_map = lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], h, 0, 0)
+    else:
+        n_steps = window_pages(window, page_size)
+        page_map = lambda bb, h, jb, ji, bt, ln: (bt[bb, jnp.minimum(
+            _window_first(ln[bb], window, page_size) + jb, max_pages - 1)], h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, max_pages // bp, bp),
+        grid=(b, hkv, n_steps, bp),
         in_specs=[
             pl.BlockSpec((1, 1, group, d), lambda bb, h, jb, ji, bt, ln: (bb, h, 0, 0)),
-            # the LayoutPaged indirection: logical page jb*bp + ji of sequence
-            # bb DMAs physical page block_tables[bb, jb*bp + ji]
-            pl.BlockSpec((1, None, page_size, d),
-                         lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], h, 0, 0)),
-            pl.BlockSpec((1, None, page_size, d),
-                         lambda bb, h, jb, ji, bt, ln: (bt[bb, jb * bp + ji], h, 0, 0)),
+            pl.BlockSpec((1, None, page_size, d), page_map),
+            pl.BlockSpec((1, None, page_size, d), page_map),
         ],
         out_specs=pl.BlockSpec((1, 1, group, d), lambda bb, h, jb, ji, bt, ln: (bb, h, 0, 0)),
         scratch_shapes=[
@@ -231,6 +268,7 @@ def paged_flash_decode(
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
         name="paged_decode",
+        **_window_tag(window),
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
@@ -244,6 +282,7 @@ def paged_decode_attention_jnp(
     *,
     scale: float | None = None,
     block_pages: int | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """jnp twin: gather each sequence's pages by table, mask by length.
 
@@ -252,7 +291,8 @@ def paged_decode_attention_jnp(
     With ``block_pages`` set, the gather is blocked: a lax.scan over page
     blocks of that width with an online-softmax carry, so peak gathered K/V is
     O(B·block_pages·page_size) — here the knob really is the working-set
-    granularity, which is what kernels/autotune.py times.
+    granularity, which is what kernels/autotune.py times. With ``window``
+    only the row's ``window_pages`` window pages are gathered.
     """
     b, hq, tq, d = q.shape
     _, hkv, page_size, _ = k_pool.shape
@@ -260,6 +300,9 @@ def paged_decode_attention_jnp(
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     max_pages = block_tables.shape[1]
+    if window is not None:
+        return _paged_decode_jnp_window(q, k_pool, v_pool, block_tables,
+                                        context_lens, scale=scale, window=window)
     if block_pages and block_pages < max_pages:
         return _paged_decode_jnp_blocked(
             q, k_pool, v_pool, block_tables, context_lens,
@@ -281,6 +324,42 @@ def paged_decode_attention_jnp(
     p = jnp.exp(s - m) * live[:, None, None, :]
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bhgk,bhkd->bhgd", p, v) / jnp.where(l == 0.0, 1.0, l)
+    return out.reshape(b, hq, 1, d).astype(q.dtype)
+
+
+def _window_table(block_tables, first, n_pages: int):
+    """(B, n_pages) logical pages first..first + n_pages - 1 of each row, and
+    their physical pages (past the table: its last entry, never live)."""
+    logical = first[:, None] + jnp.arange(n_pages)[None, :]
+    last = block_tables.shape[1] - 1
+    return logical, jnp.take_along_axis(block_tables, jnp.minimum(logical, last), axis=1)
+
+
+def _paged_decode_jnp_window(q, k_pool, v_pool, block_tables, context_lens, *,
+                             scale: float, window: int) -> jax.Array:
+    """Windowed decode twin: gather the row's window pages only, and mask
+    keys outside [n - window, n)."""
+    b, hq, _, d = q.shape
+    _, hkv, page_size, _ = k_pool.shape
+    group = hq // hkv
+    n_win = window_pages(window, page_size)
+    lens = context_lens.astype(jnp.int32)
+    logical, table = _window_table(
+        block_tables, _window_first(lens, window, page_size), n_win)
+    s_len = n_win * page_size
+    k = jnp.moveaxis(k_pool[table], 2, 1).reshape(b, hkv, s_len, d)
+    v = jnp.moveaxis(v_pool[table], 2, 1).reshape(b, hkv, s_len, d)
+    k_pos = (logical[:, :, None] * page_size
+             + jnp.arange(page_size)[None, None, :]).reshape(b, s_len)
+    live = (k_pos < lens[:, None]) & (k_pos >= lens[:, None] - window)
+    qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k.astype(jnp.float32)) * scale
+    s = jnp.where(live[:, None, None, :], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m) * live[:, None, None, :]
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
+    out = out / jnp.where(l == 0.0, 1.0, l)
     return out.reshape(b, hq, 1, d).astype(q.dtype)
 
 
@@ -519,23 +598,31 @@ def paged_decode_attention_quant_jnp(
 # the chunk tile applied as the last accumulation step.
 
 
-def _past_live(cursor, c: int, group: int, page_size: int, j):
+def _past_live(cursor, c: int, group: int, page_size: int, j, window=None):
     """(C*G, page_size) liveness of logical page j for the past part: every
     slot before the chunk start (causality across the boundary is automatic —
-    all past positions precede every chunk row). Rows are t-major blocks of
-    size G (see the reshape in the callers)."""
+    all past positions precede every chunk row), and under a ``window`` only
+    the slots within it of row t's position cursor + t. Rows are t-major
+    blocks of size G (see the reshape in the callers)."""
     rows = c * group
     k_pos = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (rows, page_size), 1
     )
-    return k_pos < cursor
+    live = k_pos < cursor
+    if window is not None:
+        t = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // group
+        live = live & (k_pos > cursor + t - window)
+    return live
 
 
-def _chunk_self_live(c: int, group: int):
-    """(C*G, C) intra-chunk causal mask: row t attends chunk column tk <= t."""
+def _chunk_self_live(c: int, group: int, window=None):
+    """(C*G, C) intra-chunk causal mask: row t attends chunk column tk <= t
+    (and tk > t - window under a window)."""
     rows = c * group
     t = jax.lax.broadcasted_iota(jnp.int32, (rows, c), 0) // group
     tk = jax.lax.broadcasted_iota(jnp.int32, (rows, c), 1)
+    if window is not None:
+        return (tk <= t) & (tk > t - window)
     return tk <= t
 
 
@@ -556,6 +643,7 @@ def _paged_chunk_kernel(
     page_size: int,
     chunk: int,
     group: int,
+    window: int | None = None,
 ):
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -567,12 +655,16 @@ def _paged_chunk_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j * page_size < cur_ref[b])
+    pg = j  # the logical page grid step j reads
+    if window is not None:
+        pg = _window_first(cur_ref[b] + 1, window, page_size) + j
+
+    @pl.when(pg * page_size < cur_ref[b])
     def _past():
         q = q_ref[0, 0].astype(jnp.float32)  # (C*G, D)
         k = k_ref[0].astype(jnp.float32)     # (page_size, D)
         v = v_ref[0].astype(jnp.float32)
-        live = _past_live(cur_ref[b], chunk, group, page_size, j)
+        live = _past_live(cur_ref[b], chunk, group, page_size, pg, window)
         _flash_update(q, k, v, live, acc_ref, m_ref, l_ref, scale=scale)
 
     @pl.when(j == nj - 1)
@@ -580,7 +672,7 @@ def _paged_chunk_kernel(
         q = q_ref[0, 0].astype(jnp.float32)
         ck = ck_ref[0, 0].astype(jnp.float32)  # (C, D)
         cv = cv_ref[0, 0].astype(jnp.float32)
-        live = _chunk_self_live(chunk, group)
+        live = _chunk_self_live(chunk, group, window)
         _flash_update(q, ck, cv, live, acc_ref, m_ref, l_ref, scale=scale)
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -597,6 +689,7 @@ def paged_flash_prefill_chunk(
     cursors: jax.Array,
     *,
     scale: float | None = None,
+    window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """GQA chunked-prefill attention: past from the pool, present from f32.
@@ -610,6 +703,10 @@ def paged_flash_prefill_chunk(
     past them) never feed back into its own attention. Rows past the chunk's
     valid length produce garbage the caller discards (their KV went to the
     null page, so nothing real ever attends them).
+
+    ``window``: row t of the chunk attends to the keys after
+    cursor + t - window, so the pool is read from the page of
+    max(0, cursor - window + 1), ``window_pages`` grid steps.
     """
     interpret = use_interpret() if interpret is None else interpret
     b, hq, c, d = q.shape
@@ -624,27 +721,29 @@ def paged_flash_prefill_chunk(
     )
 
     kern = functools.partial(
-        _paged_chunk_kernel, scale=scale, page_size=page_size, chunk=c, group=group
+        _paged_chunk_kernel, scale=scale, page_size=page_size, chunk=c, group=group,
+        window=window,
     )
     rows = c * group
     chunk_spec = pl.BlockSpec(
         (1, 1, c, d), lambda bb, h, j, bt, cur: (bb, h, 0, 0)
     )
+    if window is None:
+        n_steps = max_pages
+        page_map = lambda bb, h, j, bt, cur: (bt[bb, j], h, 0, 0)
+    else:
+        n_steps = window_pages(window, page_size)
+        page_map = lambda bb, h, j, bt, cur: (bt[bb, jnp.minimum(
+            _window_first(cur[bb] + 1, window, page_size) + j, max_pages - 1)], h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, max_pages),
+        grid=(b, hkv, n_steps),
         in_specs=[
             pl.BlockSpec((1, 1, rows, d), lambda bb, h, j, bt, cur: (bb, h, 0, 0)),
             chunk_spec,
             chunk_spec,
-            pl.BlockSpec(
-                (1, None, page_size, d),
-                lambda bb, h, j, bt, cur: (bt[bb, j], h, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, None, page_size, d),
-                lambda bb, h, j, bt, cur: (bt[bb, j], h, 0, 0),
-            ),
+            pl.BlockSpec((1, None, page_size, d), page_map),
+            pl.BlockSpec((1, None, page_size, d), page_map),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, rows, d), lambda bb, h, j, bt, cur: (bb, h, 0, 0)
@@ -661,6 +760,7 @@ def paged_flash_prefill_chunk(
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
         name="paged_prefill_chunk",
+        **_window_tag(window),
     )(
         block_tables.astype(jnp.int32), cursors.astype(jnp.int32),
         qg, chunk_k, chunk_v, k_pool, v_pool,
@@ -679,15 +779,21 @@ def paged_prefill_chunk_jnp(
     cursors: jax.Array,
     *,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """jnp twin: concatenate [gathered past pages | the chunk's own f32 K/V]
     along the key axis, mask (past below cursor, present causally), one
-    softmax — identical semantics to the kernel's two-part online update."""
+    softmax — identical semantics to the kernel's two-part online update.
+    With ``window`` only the window pages are gathered."""
     b, hq, c, d = q.shape
     _, hkv, page_size, _ = k_pool.shape
     assert hq % hkv == 0
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    if window is not None:
+        return _paged_chunk_jnp_window(q, chunk_k, chunk_v, k_pool, v_pool,
+                                       block_tables, cursors, scale=scale,
+                                       window=window)
     k = jnp.moveaxis(k_pool[block_tables], 2, 1)
     v = jnp.moveaxis(v_pool[block_tables], 2, 1)
     s_len = k.shape[2] * page_size
@@ -705,6 +811,40 @@ def paged_prefill_chunk_jnp(
     present = (t_q[None, :] <= t_q[:, None])[None]  # (1, C, C) causal
     present = jnp.broadcast_to(present, (b, c, c))
     live = jnp.concatenate([past, present], axis=-1)[:, None, None]  # (B,1,1,C,S+C)
+    s = jnp.where(live, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m) * live
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v) / jnp.where(l == 0.0, 1.0, l)
+    return out.reshape(b, hq, c, d).astype(q.dtype)
+
+
+def _paged_chunk_jnp_window(q, chunk_k, chunk_v, k_pool, v_pool, block_tables,
+                            cursors, *, scale: float, window: int) -> jax.Array:
+    """Windowed chunk twin: the window pages of the pool, then the chunk;
+    query t attends to keys after cursor + t - window."""
+    b, hq, c, d = q.shape
+    _, hkv, page_size, _ = k_pool.shape
+    group = hq // hkv
+    n_win = window_pages(window, page_size)
+    cur = cursors.astype(jnp.int32)
+    logical, table = _window_table(
+        block_tables, _window_first(cur + 1, window, page_size), n_win)
+    s_len = n_win * page_size
+    k = jnp.moveaxis(k_pool[table], 2, 1).reshape(b, hkv, s_len, d)
+    v = jnp.moveaxis(v_pool[table], 2, 1).reshape(b, hkv, s_len, d)
+    k = jnp.concatenate([k, chunk_k.astype(k.dtype)], axis=2).astype(jnp.float32)
+    v = jnp.concatenate([v, chunk_v.astype(v.dtype)], axis=2).astype(jnp.float32)
+    k_pos = (logical[:, :, None] * page_size
+             + jnp.arange(page_size)[None, None, :]).reshape(b, 1, s_len)
+    q_pos = cur[:, None, None] + jnp.arange(c)[None, :, None]  # (B, C, 1)
+    past = (k_pos < cur[:, None, None]) & (k_pos > q_pos - window)
+    t_q = jnp.arange(c)
+    present = (t_q[None, :] <= t_q[:, None]) & (t_q[None, :] > t_q[:, None] - window)
+    present = jnp.broadcast_to(present[None], (b, c, c))
+    live = jnp.concatenate([past, present], axis=-1)[:, None, None]
+    qg = q.reshape(b, hkv, group, c, d).astype(jnp.float32)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k) * scale
     s = jnp.where(live, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m) * live

@@ -202,8 +202,8 @@ def self_attention(
     b, t, d = x.shape
     q, k, v = _project_qkv(cfg, p, x)
     pos = jnp.arange(t) + pos_offset
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = apply_rope(q, pos, cfg.rope_theta, cfg.yarn)
+    k = apply_rope(k, pos, cfg.rope_theta, cfg.yarn)
     q = shard(q, "batch", "heads", "seq", None)
     k = shard(k, "batch", "kv_heads", "seq", None)
     out = ops.attention(q, k, v, causal=causal, window=window, q_offset=pos_offset, impl="jnp")
@@ -295,8 +295,8 @@ def self_attention_decode(
     s_len = cache["k"].shape[2]
     q, k, v = _project_qkv(cfg, p, x)
     posv = jnp.reshape(jnp.asarray(pos, jnp.int32), (1,))
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    q = apply_rope(q, posv, cfg.rope_theta, cfg.yarn)
+    k = apply_rope(k, posv, cfg.rope_theta, cfg.yarn)
     # distributed flash-decode when the cache's seq dim is sharded over "model"
     mesh = getattr(shard, "mesh", None)
     if (
@@ -332,6 +332,14 @@ def self_attention_decode(
         out = jnp.einsum("bhqk,bhkd->bhqd", pr, vf).astype(x.dtype)
     y = _out_proj(p, out, x.dtype)
     return y, {"k": ck, "v": cv}
+
+
+def refuse_quantized_window(kv_spec, window) -> None:
+    """Windowed attention has no quantized-pool path: refuse the pair."""
+    if kv_spec is not None and window is not None:
+        raise NotImplementedError(
+            "quantized KV pools have no sliding-window attention path: serve "
+            "a windowed model with bf16 pages (kv_dtype='f32')")
 
 
 def _append_tokens(pool, tok, page, slot):
@@ -376,6 +384,7 @@ def self_attention_decode_paged(
     impl: str = "auto",
     kv_spec=None,
     block_pages: int | None = None,
+    window: Optional[int] = None,
 ):
     """One-token decode against a paged KV pool (the LayoutPaged cache adapter).
 
@@ -393,17 +402,20 @@ def self_attention_decode_paged(
 
     ``block_pages`` is the autotuned kernel block-shape knob, forwarded
     verbatim to ops.paged_decode_attention{,_quant} (None = unblocked).
+    ``window`` (sliding-window layers) reads only the row's last ``window``
+    keys; it has no quantized path.
 
     Single-host path: ``shard`` is accepted for API symmetry with
     self_attention_decode but no mesh-aware variant exists yet — on a mesh the
     page pool replicates (multi-host paging is a ROADMAP open item).
     """
     b, _, d = x.shape
+    refuse_quantized_window(kv_spec, window)
     ps = cache["k"]["q"].shape[2] if kv_spec is not None else cache["k"].shape[2]
     q, k, v = _project_qkv(cfg, p, x)
     pos = jnp.asarray(context_lens, jnp.int32)  # (B,)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    q = apply_rope(q, pos[:, None], cfg.rope_theta, cfg.yarn)
+    k = apply_rope(k, pos[:, None], cfg.rope_theta, cfg.yarn)
     page = block_tables[jnp.arange(b), pos // ps]  # (B,)
     slot = pos % ps
     if kv_spec is not None:
@@ -417,7 +429,8 @@ def self_attention_decode_paged(
         ck = _append_tokens(cache["k"], k[:, :, 0, :], page, slot)
         cv = _append_tokens(cache["v"], v[:, :, 0, :], page, slot)
         out = ops.paged_decode_attention(
-            q, ck, cv, block_tables, pos + 1, block_pages=block_pages, impl=impl
+            q, ck, cv, block_tables, pos + 1, block_pages=block_pages,
+            window=window, impl=impl,
         )
     y = _out_proj(p, out, x.dtype)
     return y, {"k": ck, "v": cv}
@@ -460,8 +473,8 @@ def self_attention_verify_paged(
     q, k, v = _project_qkv(cfg, p, x)  # (B, H, C, Dh)
     lens = jnp.asarray(context_lens, jnp.int32)
     pos = lens[:, None] + jnp.arange(c)[None, :]  # (B, C)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = apply_rope(q, pos, cfg.rope_theta, cfg.yarn)
+    k = apply_rope(k, pos, cfg.rope_theta, cfg.yarn)
     rows = jnp.arange(b)
     ck, cv = cache["k"], cache["v"]
     pages, slots = [], []
@@ -555,6 +568,7 @@ def self_attention_prefill_chunk_paged(
     impl: str = "auto",
     kv_spec=None,
     null_page=0,
+    window: Optional[int] = None,
 ):
     """One prefill CHUNK against a paged KV pool — the mixed-step prefill half.
 
@@ -576,15 +590,18 @@ def self_attention_prefill_chunk_paged(
     accessor exactly as in the decode path. ``null_page`` is where the
     chunk-bucket pad pages land: the pool's own null page (``l·P`` when the
     pool is a layer stack's flat page space, Model.decode_step_paged).
+    ``window``: each query attends to the keys within the window of its own
+    position (sliding-window layers); no quantized path.
     """
     b, c, d = x.shape
+    refuse_quantized_window(kv_spec, window)
     ps = cache["k"]["q"].shape[2] if kv_spec is not None else cache["k"].shape[2]
     npg = c // ps
     max_pages = block_tables.shape[1]
     q, k, v = _project_qkv(cfg, p, x)  # (B, H, C, Dh)
     pos = cursors[:, None] + jnp.arange(c)[None, :]  # (B, C)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = apply_rope(q, pos, cfg.rope_theta, cfg.yarn)
+    k = apply_rope(k, pos, cfg.rope_theta, cfg.yarn)
     hkv, dh = k.shape[1], k.shape[3]
     # page-factor the chunk KV: (B, Hkv, C, Dh) -> (B, nP, Hkv, ps, Dh)
     kp = jnp.swapaxes(k.reshape(b, hkv, npg, ps, dh), 1, 2)
@@ -609,7 +626,7 @@ def self_attention_prefill_chunk_paged(
         )
     else:
         out = ops.paged_prefill_chunk_attention(
-            q, k, v, ck, cv, block_tables, cursors, impl=impl
+            q, k, v, ck, cv, block_tables, cursors, window=window, impl=impl
         )
     y = _out_proj(p, out, x.dtype)
     return y, {"k": ck, "v": cv}

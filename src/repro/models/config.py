@@ -25,6 +25,15 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     window: Optional[int] = None  # local attention window (hybrid archs)
+    # per-layer attention kinds, as HF's ``layer_types``: "sliding_attention"
+    # layers attend to the last ``window`` keys, "full_attention" layers to
+    # every key; () = every layer alike. The list must repeat a period.
+    layer_types: Tuple[str, ...] = ()
+    # YaRN RoPE of the full-attention layers (HF ``rope_type: yarn``, its
+    # default beta_fast 32, beta_slow 1 and attention factor 0.1 ln(factor)
+    # + 1): ``yarn_factor`` 0 = plain RoPE at ``rope_theta`` on every layer
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
     # mlp
     d_ff: int = 0
     mlp_act: str = "swiglu"  # swiglu | gelu
@@ -33,6 +42,11 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # routed experts held here: experts [expert_rank * experts_held,
+    # (expert_rank + 1) * experts_held) of the router's n_experts (the chip's
+    # share under expert parallelism); 0 = all of them
+    experts_held: int = 0
+    expert_rank: int = 0
     # ssm (mamba2 SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -54,6 +68,11 @@ class ModelConfig:
     vocab_pad_to: int = 256
     tie_embeddings: bool = False
 
+    def __post_init__(self):
+        # JSON configurations give lists; keep the config hashable
+        for f in ("layer_types", "pattern"):
+            object.__setattr__(self, f, tuple(getattr(self, f)))
+
     # ------------------------------------------------------------------
     @property
     def head_dim(self) -> int:
@@ -66,6 +85,16 @@ class ModelConfig:
     @property
     def param_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def yarn(self) -> Optional[Tuple[float, int]]:
+        """(factor, original max positions) of YaRN RoPE, or None for plain
+        RoPE."""
+        return (self.yarn_factor, self.yarn_original_max) if self.yarn_factor else None
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
 
     # ssm derived
     @property

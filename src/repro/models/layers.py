@@ -8,10 +8,12 @@ the plain buffer pytrees those specs initialize. Quantized weights arrive as
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
 from repro.core.accessors import QuantizedAccessor
@@ -130,10 +132,45 @@ def rope_freqs(d_head: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, H, T, D); positions: (T,) or (B, T) absolute positions."""
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0  # transformers' defaults
+
+
+def yarn_attention_factor(factor: float) -> float:
+    """YaRN's scale of cos and sin (transformers' default: 0.1 ln s + 1)."""
+    return 0.1 * math.log(factor) + 1.0
+
+
+def yarn_freqs(d_head: int, theta: float, factor: float, original_max: int) -> np.ndarray:
+    """YaRN's inverse frequencies (arXiv:2309.00071), as transformers'
+    ``_compute_yarn_parameters`` gives them (``truncate`` on): dimensions
+    that turn fewer than ``YARN_BETA_SLOW`` times over ``original_max``
+    positions are interpolated by ``factor``, those that turn more than
+    ``YARN_BETA_FAST`` times are kept, and a linear ramp joins the two."""
+    half = d_head // 2
+    pos_freqs = theta ** (np.arange(0, d_head, 2, dtype=np.float64) / d_head)
+
+    def corr_dim(rotations):
+        return (d_head * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(corr_dim(YARN_BETA_SLOW)), d_head - 1)
+    ramp = np.clip((np.arange(half) - low) / ((high - low) or 0.001), 0, 1)
+    keep = 1.0 - ramp
+    inv = (1.0 / (factor * pos_freqs)) * (1 - keep) + (1.0 / pos_freqs) * keep
+    return inv.astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (B, H, T, D); positions: (T,) or (B, T) absolute positions.
+    ``yarn`` (ModelConfig.yarn) switches to YaRN's frequencies, and its
+    attention factor scales cos and sin, so q . k grows by its square."""
     b, h, t, d = x.shape
-    freqs = rope_freqs(d, theta)  # (d/2,)
+    if yarn is None:
+        freqs = rope_freqs(d, theta)  # (d/2,)
+    else:
+        freqs = jnp.asarray(yarn_freqs(d, theta, *yarn))
     if positions.ndim == 1:
         ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (T, d/2)
         ang = ang[None, None]  # (1, 1, T, d/2)
@@ -141,6 +178,9 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         ang = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
         ang = ang[:, None]  # (B, 1, T, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        scale = yarn_attention_factor(yarn[0])
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -22,18 +22,93 @@ from .layers import NULL_SHARDER, Sharder
 def moe_specs(cfg, *, quant=None) -> Dict[str, TensorSpec]:
     from .layers import fit_quant
 
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    e, d, f = cfg.n_held_experts, cfg.d_model, cfg.d_ff
     dt = cfg.param_dtype
 
     def mk(shape, axes):
         q = fit_quant(quant, shape[-1])
         return TensorSpec(shape, axes, dtype=dt, init="fan_in", accessor=q)
     return {
-        "router": TensorSpec((d, e), ("embed", None), dtype=jnp.float32, init="fan_in"),
+        "router": TensorSpec((d, cfg.n_experts), ("embed", None), dtype=jnp.float32,
+                             init="fan_in"),
         "w_gate": mk((e, d, f), ("expert", "embed", "expert_ffn")),
         "w_up": mk((e, d, f), ("expert", "embed", "expert_ffn")),
         "w_down": mk((e, f, d), ("expert", "expert_ffn", "embed")),
     }
+
+
+def apply_moe_dropless(cfg, p, x: jax.Array, valid: Optional[jax.Array] = None,
+                       impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+    """The serving paths' routed layer: no token is ever dropped, so a
+    token's output does not depend on what else is in its batch.
+
+    The router scores all ``cfg.n_experts`` experts (softmax, top-k,
+    renormalised over the k); of the (token, expert) pairs, only those whose
+    expert is held here, experts [expert_rank * held, (expert_rank + 1) *
+    held), are computed (``p`` holds those experts' weights). The pairs are
+    sorted by expert and multiplied as one grouped product per projection
+    (``_grouped``): work in proportion to the held pairs, no capacity buffer.
+    What the experts held elsewhere would add is left out; on one chip the
+    layer runs without its exchange.
+
+    ``p`` may hold every layer's experts, (L, held, ...), with ``p["layer"]``
+    naming this one: the grouped product then reads its layer's experts in
+    place, and no step copies a layer's expert weights out of the stack.
+
+    x: (B, S, D); ``valid`` (B, S) marks the tokens the counts count (pad
+    rows are computed, but not counted). Returns (y, counts), counts (3,)
+    int32: routed pairs, held pairs, and held experts with a pair."""
+    b, s, d = x.shape
+    t = b * s
+    k, held = cfg.top_k, cfg.n_held_experts
+    lo = cfg.expert_rank * held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.route"):
+        logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, k)  # (T, k)
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        local = idx.reshape(-1) - lo  # (T*k,) expert ids of this chip's share
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)  # pairs held elsewhere sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        n_mine = jnp.sum(sizes)
+        token_of = order // k  # held pairs first, grouped by expert
+        live = jnp.arange(t * k) < n_mine
+        w = jnp.where(live, gate.reshape(-1)[order], 0.0)
+        ok = jnp.ones((t,), bool) if valid is None else valid.reshape(t)
+        ok_pairs = jnp.repeat(ok, k) & mine
+        per_expert = jnp.bincount(jnp.where(ok_pairs, local, held), length=held + 1)
+        counts = jnp.stack([jnp.sum(ok) * k, jnp.sum(ok_pairs),
+                            jnp.sum(per_expert[:held] > 0)]).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        layer = p.get("layer")
+        rows = xt[token_of]  # (T*k, D)
+        g = _grouped(rows, p["w_gate"], sizes, layer, cfg, impl)
+        u = _grouped(rows, p["w_up"], sizes, layer, cfg, impl)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = _grouped(h, p["w_down"], sizes, layer, cfg, impl)
+        # rows past the held pairs belong to no group: never read them
+        y = jnp.where(live[:, None], y, 0.0) * w[:, None]
+        # back to (token, choice) order by gathering, then each token's k
+        # choices summed in the router's order
+        unsort = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
+        out = y[unsort].reshape(t, k, d).sum(axis=1)
+    return out.astype(x.dtype).reshape(b, s, d), counts
+
+
+def _grouped(rows, w, sizes, layer, cfg, impl: str) -> jax.Array:
+    """ops.grouped_matmul over this layer's held experts; quantized experts
+    are dequantized first."""
+    from repro.kernels import ops
+
+    if isinstance(w, dict):
+        if layer is not None:
+            w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), w)
+            layer = None
+        w = _deq(w, cfg)
+    return ops.grouped_matmul(rows, w.astype(rows.dtype), sizes, layer=layer, impl=impl)
 
 
 def _capacity(cfg, n_tokens: int) -> int:

@@ -22,6 +22,7 @@ the MoE load-balance loss (0 elsewhere).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
@@ -81,6 +82,20 @@ def stack_specs(specs, n: int):
 # =====================================================================================
 # block kinds
 # =====================================================================================
+def _attn_scope(cfg, window):
+    """Names the attention of a model whose layers differ in it, so a device
+    trace can put time on each kind; nothing for one-kind models."""
+    if not cfg.layer_types:
+        return contextlib.nullcontext()
+    return jax.named_scope("attn.window" if window is not None else "attn.full")
+
+
+def _paged_out(x, cache, counts):
+    """A paged layer's result: (x, pool), and the routing counts of a routed
+    layer as a third item."""
+    return (x, cache) if counts is None else (x, cache, counts)
+
+
 class DenseBlock:
     def __init__(self, use_window: bool = False, causal: bool = True):
         self.use_window = use_window
@@ -88,6 +103,12 @@ class DenseBlock:
 
     def _window(self, cfg):
         return cfg.window if self.use_window else None
+
+    def _ffn(self, cfg, p, x, shard, valid=None, impl="auto"):
+        """x plus the layer's feed-forward of its norm, and the routing counts
+        (None: no experts). ``valid`` (B, T) marks the tokens counted."""
+        h = apply_norm(cfg, x, p["ln_mlp"])
+        return x + apply_mlp(cfg, p["mlp"], h, shard), None
 
     def specs(self, cfg, quant=None):
         return {
@@ -112,16 +133,17 @@ class DenseBlock:
         x = x + apply_mlp(cfg, p["mlp"], h, shard)
         return x, jnp.float32(0)
 
-    def prefill(self, cfg, p, x, shard, ctx=None, max_len=None):
+    def prefill(self, cfg, p, x, shard, ctx=None, max_len=None, ring=True):
+        """``ring`` False keeps a windowed layer's K/V at every position (the
+        paged pool's layout) instead of a ring of the window."""
         h = apply_norm(cfg, x, p["ln_attn"])
         y, (k, v) = attn.self_attention(
             cfg, p["attn"], h, shard=shard, causal=self.causal,
             window=self._window(cfg), return_kv=True,
         )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_mlp"])
-        x = x + apply_mlp(cfg, p["mlp"], h, shard)
-        cache = attn.pack_kv_cache(cfg, k, v, max_len=max_len, window=self._window(cfg))
+        x, _ = self._ffn(cfg, p, x + y, shard)
+        cache = attn.pack_kv_cache(cfg, k, v, max_len=max_len,
+                                   window=self._window(cfg) if ring else None)
         return x, cache
 
     def decode(self, cfg, p, x, cache, pos, shard, ctx=None):
@@ -129,55 +151,60 @@ class DenseBlock:
         y, cache = attn.self_attention_decode(
             cfg, p["attn"], h, cache, pos, shard=shard, window=self._window(cfg)
         )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_mlp"])
-        x = x + apply_mlp(cfg, p["mlp"], h, shard)
+        x, _ = self._ffn(cfg, p, x + y, shard)
         return x, cache
 
     def paged_cache_specs(self, cfg, num_pages: int, page_size: int, kv_spec=None):
-        if self._window(cfg) is not None:
-            raise NotImplementedError("paged KV caching does not support local windows")
+        attn.refuse_quantized_window(kv_spec, self._window(cfg))
         return attn.paged_cache_specs(cfg, num_pages, page_size, kv_spec=kv_spec)
 
     def decode_paged(self, cfg, p, x, cache, block_tables, context_lens, shard,
-                     impl: str = "auto", kv_spec=None, block_pages=None):
+                     impl: str = "auto", kv_spec=None, block_pages=None, valid=None):
         h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_decode_paged(
-            cfg, p["attn"], h, cache, block_tables, context_lens, shard=shard,
-            impl=impl, kv_spec=kv_spec, block_pages=block_pages,
-        )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_mlp"])
-        x = x + apply_mlp(cfg, p["mlp"], h, shard)
-        return x, cache
+        w = self._window(cfg)
+        with _attn_scope(cfg, w):
+            y, cache = attn.self_attention_decode_paged(
+                cfg, p["attn"], h, cache, block_tables, context_lens, shard=shard,
+                impl=impl, kv_spec=kv_spec, block_pages=block_pages, window=w,
+            )
+        x, counts = self._ffn(cfg, p, x + y, shard, valid, impl)
+        return _paged_out(x, cache, counts)
 
     def prefill_chunk_paged(self, cfg, p, x, cache, block_tables, write_tables,
                             cursors, n_new, shard, impl: str = "auto", kv_spec=None,
-                            null_page=0):
+                            null_page=0, valid=None):
         h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_prefill_chunk_paged(
-            cfg, p["attn"], h, cache, block_tables, write_tables, cursors, n_new,
-            shard=shard, impl=impl, kv_spec=kv_spec, null_page=null_page,
-        )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_mlp"])
-        x = x + apply_mlp(cfg, p["mlp"], h, shard)
-        return x, cache
+        w = self._window(cfg)
+        with _attn_scope(cfg, w):
+            y, cache = attn.self_attention_prefill_chunk_paged(
+                cfg, p["attn"], h, cache, block_tables, write_tables, cursors, n_new,
+                shard=shard, impl=impl, kv_spec=kv_spec, null_page=null_page,
+                window=w,
+            )
+        x, counts = self._ffn(cfg, p, x + y, shard, valid, impl)
+        return _paged_out(x, cache, counts)
 
     def verify_paged(self, cfg, p, x, cache, block_tables, context_lens, shard,
                      impl: str = "auto", kv_spec=None):
+        if self._window(cfg) is not None:
+            raise NotImplementedError(
+                "speculative verify has no sliding-window attention path: "
+                "serve a windowed model with spec_tokens=0")
         h = apply_norm(cfg, x, p["ln_attn"])
         y, cache = attn.self_attention_verify_paged(
             cfg, p["attn"], h, cache, block_tables, context_lens, shard=shard,
             impl=impl, kv_spec=kv_spec,
         )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_mlp"])
-        x = x + apply_mlp(cfg, p["mlp"], h, shard)
-        return x, cache
+        x, counts = self._ffn(cfg, p, x + y, shard, impl=impl)
+        return _paged_out(x, cache, counts)
 
 
 class MoEBlock(DenseBlock):
+    """Attention and routed experts. Training routes by capacity
+    (``apply_moe_dispatch``); every serving path routes dropless over the
+    experts held here (``apply_moe_dropless``), so a request's output does
+    not depend on its batch."""
+
     def specs(self, cfg, quant=None):
         return {
             "ln_attn": norm_specs(cfg),
@@ -186,65 +213,81 @@ class MoEBlock(DenseBlock):
             "moe": moe_mod.moe_specs(cfg, quant=quant),
         }
 
+    def _ffn(self, cfg, p, x, shard, valid=None, impl="auto"):
+        h = apply_norm(cfg, x, p["ln_moe"])
+        y, counts = moe_mod.apply_moe_dropless(cfg, p["moe"], h, valid, impl)
+        return x + y, counts
+
     def train(self, cfg, p, x, shard, ctx=None, pos_offset=0):
         h = apply_norm(cfg, x, p["ln_attn"])
-        x = x + attn.self_attention(cfg, p["attn"], h, shard=shard, pos_offset=pos_offset)
+        x = x + attn.self_attention(cfg, p["attn"], h, shard=shard,
+                                    window=self._window(cfg), pos_offset=pos_offset)
         h = apply_norm(cfg, x, p["ln_moe"])
         y, aux = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
         return x + y, aux
 
-    def prefill(self, cfg, p, x, shard, ctx=None, max_len=None):
-        h = apply_norm(cfg, x, p["ln_attn"])
-        y, (k, v) = attn.self_attention(cfg, p["attn"], h, shard=shard, return_kv=True)
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_moe"])
-        y, _ = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
-        return x + y, attn.pack_kv_cache(cfg, k, v, max_len=max_len, window=None)
 
-    def decode(self, cfg, p, x, cache, pos, shard, ctx=None):
-        h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_decode(cfg, p["attn"], h, cache, pos, shard=shard)
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_moe"])
-        y, _ = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
-        return x + y, cache
+class PeriodBlock:
+    """A repeating period of layers whose attention differs, as HF's
+    ``layer_types`` gives it (Mellum2: three sliding-window layers, then a
+    full one), each with the family's feed-forward (a gated MLP, or routed
+    experts). Parameters and KV pools stack per layer, (n_periods · period,
+    ...), as a plain stack of as many layers would; the step scans the
+    periods and unrolls the period, so each layer's window is static in its
+    kernels. Sliding layers rotate at ``rope_theta``; full layers take
+    ``cfg.yarn`` when it is set. It serves through the paged engine and its
+    monolithic prefill only: the contiguous decode cache has no place for
+    layers of two sizes, and training has no held-expert layer."""
 
-    def decode_paged(self, cfg, p, x, cache, block_tables, context_lens, shard,
-                     impl: str = "auto", kv_spec=None, block_pages=None):
-        h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_decode_paged(
-            cfg, p["attn"], h, cache, block_tables, context_lens, shard=shard,
-            impl=impl, kv_spec=kv_spec, block_pages=block_pages,
-        )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_moe"])
-        y, _ = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
-        return x + y, cache
+    def units(self, cfg):
+        """(block, layer config) of each layer of the period."""
+        inner = MoEBlock if cfg.family == "moe" else DenseBlock
+        out = []
+        for kind in period_of(cfg):
+            if kind == "sliding_attention":
+                out.append((inner(use_window=True),
+                            dataclasses.replace(cfg, yarn_factor=0.0)))
+            elif kind == "full_attention":
+                out.append((inner(), dataclasses.replace(cfg, window=None)))
+            else:
+                raise ValueError(f"unknown layer type {kind!r}")
+        return out
 
-    def prefill_chunk_paged(self, cfg, p, x, cache, block_tables, write_tables,
-                            cursors, n_new, shard, impl: str = "auto", kv_spec=None,
-                            null_page=0):
-        h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_prefill_chunk_paged(
-            cfg, p["attn"], h, cache, block_tables, write_tables, cursors, n_new,
-            shard=shard, impl=impl, kv_spec=kv_spec, null_page=null_page,
-        )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_moe"])
-        y, _ = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
-        return x + y, cache
+    def specs(self, cfg, quant=None):
+        return self.units(cfg)[0][0].specs(cfg, quant)
 
-    def verify_paged(self, cfg, p, x, cache, block_tables, context_lens, shard,
-                     impl: str = "auto", kv_spec=None):
-        h = apply_norm(cfg, x, p["ln_attn"])
-        y, cache = attn.self_attention_verify_paged(
-            cfg, p["attn"], h, cache, block_tables, context_lens, shard=shard,
-            impl=impl, kv_spec=kv_spec,
-        )
-        x = x + y
-        h = apply_norm(cfg, x, p["ln_moe"])
-        y, _ = moe_mod.apply_moe_dispatch(cfg, p["moe"], h, shard)
-        return x + y, cache
+    def paged_cache_specs(self, cfg, num_pages: int, page_size: int, kv_spec=None):
+        # every layer's pool is alike; each layer refuses what it cannot serve
+        specs = [blk.paged_cache_specs(c, num_pages, page_size, kv_spec)
+                 for blk, c in self.units(cfg)]
+        return specs[0]
+
+    def cache_specs(self, cfg, batch: int, seq: int):
+        raise NotImplementedError(
+            "layers of mixed attention serve through the paged engine "
+            "(Model.decode_step_paged), not the contiguous decode cache")
+
+
+def layer_params(p, layer):
+    """Layer ``layer``'s parameters out of a per-layer stack, indexed in the
+    step. A routed layer's experts stay the whole stack, ``layer`` beside
+    them: the grouped product reads that layer's experts in place
+    (``moe.apply_moe_dropless``)."""
+    pick = lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+    out = jax.tree.map(pick, {k: v for k, v in p.items() if k != "moe"})
+    if "moe" in p:
+        out["moe"] = dict(p["moe"], router=pick(p["moe"]["router"]), layer=layer)
+    return out
+
+
+def period_of(cfg) -> Tuple[str, ...]:
+    """The shortest period that ``cfg.layer_types`` repeats."""
+    lt = cfg.layer_types
+    if len(lt) != cfg.n_layers:
+        raise ValueError(f"{len(lt)} layer types for {cfg.n_layers} layers")
+    for n in range(1, len(lt) + 1):
+        if len(lt) % n == 0 and lt == lt[:n] * (len(lt) // n):
+            return lt[:n]
 
 
 class SSMBlock:
@@ -469,6 +512,7 @@ class VisGroup:
 
 KINDS: Dict[str, Any] = {
     "dense": DenseBlock(),
+    "period": PeriodBlock(),
     "local_attn": DenseBlock(use_window=True),
     "enc": DenseBlock(causal=False),
     "moe": MoEBlock(),
@@ -484,6 +528,9 @@ KINDS: Dict[str, Any] = {
 # model programs
 # =====================================================================================
 def block_program(cfg) -> List[Tuple[str, int]]:
+    """(block kind, count) entries; a ``period`` entry counts periods."""
+    if cfg.layer_types and cfg.family in ("dense", "moe"):
+        return [("period", cfg.n_layers // len(period_of(cfg)))]
     if cfg.family in ("dense",):
         return [("dense", cfg.n_layers)]
     if cfg.family == "moe":
@@ -519,12 +566,26 @@ class Model:
     cfg: Any
     quant: Optional[QuantizedAccessor] = None  # serving-weight accessor
 
+    # ---- program entries ----------------------------------------------------------
+    def _units(self, kind: str):
+        """(block, layer config) of each layer one scan step of ``kind`` runs:
+        one for a plain kind, a period's layers for ``period``."""
+        blk = KINDS[kind]
+        return blk.units(self.cfg) if isinstance(blk, PeriodBlock) else [(blk, self.cfg)]
+
+    @property
+    def routes_experts(self) -> bool:
+        """The paged steps can return routing counts (decode_step_paged's
+        ``stats``)."""
+        return self.cfg.family == "moe"
+
     # ---- specs -----------------------------------------------------------------
     def param_specs(self):
         cfg = self.cfg
         specs: Dict[str, Any] = {"embed": embed_specs(cfg)}
         specs["blocks"] = [
-            stack_specs(KINDS[k].specs(cfg, self.quant), n) for k, n in block_program(cfg)
+            stack_specs(KINDS[k].specs(cfg, self.quant), n * len(self._units(k)))
+            for k, n in block_program(cfg)
         ]
         specs["final_norm"] = norm_specs(cfg)
         if cfg.family == "encdec":
@@ -631,12 +692,26 @@ class Model:
         x = shard(x, "batch", "seq", None)
         caches = []
         for (kind, n), p in zip(block_program(cfg), params["blocks"]):
-            blk = KINDS[kind]
+            units = self._units(kind)
+            if len(units) == 1:
+                def body(xc, pl, _blk=units[0][0]):
+                    return _blk.prefill(cfg, pl, xc, shard, ctx=ctx, max_len=max_len)
 
-            def body(xc, pl, _blk=blk):
-                return _blk.prefill(cfg, pl, xc, shard, ctx=ctx, max_len=max_len)
+                x, cache = stack_scan(body, x, p)
+            else:
+                # a period's layers keep K/V at every position, as the pool
+                # does; their caches stack per layer like the parameters
+                def body(xc, step, _units=units, _p=p):
+                    out = []
+                    for i, (blk, c) in enumerate(_units):
+                        pli = layer_params(_p, step * len(_units) + i)
+                        xc, ci = blk.prefill(c, pli, xc, shard, max_len=max_len,
+                                             ring=False)
+                        out.append(ci)
+                    return xc, jax.tree.map(lambda *a: jnp.stack(a), *out)
 
-            x, cache = stack_scan(body, x, p)
+                x, cache = stack_scan(body, x, jnp.arange(n, dtype=jnp.int32))
+                cache = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), cache)
             caches.append(cache)
         x = apply_norm(cfg, x, params["final_norm"])
         if last_index is None:
@@ -656,7 +731,8 @@ class Model:
                     f"paged KV caching supports dense-attention blocks; got {kind!r}"
                 )
         return [
-            stack_specs(KINDS[k].paged_cache_specs(cfg, num_pages, page_size, kv_spec), n)
+            stack_specs(KINDS[k].paged_cache_specs(cfg, num_pages, page_size, kv_spec),
+                        n * len(self._units(k)))
             for k, n in block_program(cfg)
         ]
 
@@ -670,7 +746,7 @@ class Model:
                           shard: Sharder = NULL_SHARDER, attn_impl: str = "auto",
                           kv_spec=None, write_tables=None, n_new=None,
                           last_index=None, active=None, block_pages=None,
-                          spec_verify: bool = False):
+                          spec_verify: bool = False, stats: bool = False):
         """The MIXED serving step: decode rows and prefill chunks are the same
         computation at different widths.
 
@@ -717,45 +793,69 @@ class Model:
         folded into the paged layout's codomain: no layer of the pool is
         sliced out of a scanned input or restacked into a scanned output, so
         the step never copies the pool. The caller sees the (L, P, ...)
-        pytree it passed in."""
+        pytree it passed in. A ``period`` entry's scan step runs the period's
+        layers in turn, layer ``l·span + i`` at flat page ``(l·span + i)·P``.
+
+        ``stats=True`` (a routed model) returns a third item, (3,) int32
+        routing counts of the step's real tokens summed over its layers:
+        pairs routed, pairs held here, held experts with a pair (decode and
+        chunk steps; the verify step counts nothing)."""
         cfg = self.cfg
         chunk = tokens.ndim == 2 and not spec_verify
         if active is not None and not chunk:
             block_tables = jnp.where(active[:, None] > 0, block_tables, 0)
             context_lens = jnp.where(active > 0, context_lens, 0)
+        valid = None
+        if stats and chunk:
+            valid = jnp.arange(tokens.shape[1])[None, :] < n_new[:, None]
+        elif stats and active is not None and not spec_verify:
+            valid = active[:, None] > 0
         x = apply_embed(params["embed"], tokens if tokens.ndim == 2 else tokens[:, None])
         if cfg.family == "hybrid":
             x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
         new_caches = []
+        counts = jnp.zeros((3,), jnp.int32)
         for (kind, n), p, cache in zip(block_program(cfg), params["blocks"], caches):
-            blk = KINDS[kind]
+            units = self._units(kind)
             n_pages = jax.tree.leaves(cache)[0].shape[1]
             flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), cache)
 
-            def body(carry, pl_layer, _blk=blk, _n_pages=n_pages):
+            def body(carry, pl_layer, _units=units, _n_pages=n_pages, _p=p):
                 xc, pool = carry
-                pl, layer = pl_layer
-                base = layer * _n_pages
-                tables = block_tables + base
-                if chunk:
-                    xc, pool = _blk.prefill_chunk_paged(
-                        cfg, pl, xc, pool, tables, write_tables + base,
-                        context_lens, n_new, shard, impl=attn_impl,
-                        kv_spec=kv_spec, null_page=base,
-                    )
-                elif spec_verify:
-                    xc, pool = _blk.verify_paged(
-                        cfg, pl, xc, pool, tables, context_lens, shard,
-                        impl=attn_impl, kv_spec=kv_spec,
-                    )
-                else:
-                    xc, pool = _blk.decode_paged(
-                        cfg, pl, xc, pool, tables, context_lens, shard,
-                        impl=attn_impl, kv_spec=kv_spec, block_pages=block_pages,
-                    )
-                return (xc, pool), None
+                # a period's step indexes its layers out of the whole stack
+                # (layer_params): no period-sized slice of the weights exists
+                pl, layer = pl_layer if len(_units) == 1 else (_p, pl_layer)
+                span, step_counts = len(_units), []
+                for i, (blk, c) in enumerate(_units):
+                    pli = pl if span == 1 else layer_params(pl, layer * span + i)
+                    base = (layer * span + i) * _n_pages
+                    tables = block_tables + base
+                    if chunk:
+                        out = blk.prefill_chunk_paged(
+                            c, pli, xc, pool, tables, write_tables + base,
+                            context_lens, n_new, shard, impl=attn_impl,
+                            kv_spec=kv_spec, null_page=base, valid=valid,
+                        )
+                    elif spec_verify:
+                        out = blk.verify_paged(
+                            c, pli, xc, pool, tables, context_lens, shard,
+                            impl=attn_impl, kv_spec=kv_spec,
+                        )
+                    else:
+                        out = blk.decode_paged(
+                            c, pli, xc, pool, tables, context_lens, shard,
+                            impl=attn_impl, kv_spec=kv_spec, block_pages=block_pages,
+                            valid=valid,
+                        )
+                    xc, pool = out[:2]
+                    step_counts += out[2:]
+                return (xc, pool), (sum(step_counts) if stats and step_counts else None)
 
-            (x, flat), _ = stack_scan(body, (x, flat), (p, jnp.arange(n, dtype=jnp.int32)))
+            steps = jnp.arange(n, dtype=jnp.int32)
+            (x, flat), ys = stack_scan(body, (x, flat),
+                                       (p, steps) if len(units) == 1 else steps)
+            if ys is not None:
+                counts = counts + ys.sum(axis=0)
             new_caches.append(jax.tree.map(lambda f, a: f.reshape(a.shape), flat, cache))
         x = apply_norm(cfg, x, params["final_norm"])
         if spec_verify:
@@ -770,6 +870,8 @@ class Model:
                 x, jnp.asarray(last_index, jnp.int32)[:, None, None], axis=1
             )
         logits = apply_lm_head(cfg, params["embed"], x)
+        if stats:
+            return logits[:, 0], new_caches, counts
         return logits[:, 0], new_caches
 
     def decode_step(self, params, caches, tokens: jax.Array, pos, *,

@@ -65,7 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -361,6 +361,15 @@ class ServeEngine:
         self._c_pf_computed = self.registry.counter("prefill_tokens_computed")
         self._c_pf_skipped = self.registry.counter("prefill_tokens_skipped")
         self._c_slow = self.registry.counter("slow_steps")
+        # a routed model's steps count their (token, expert) pairs on device;
+        # the counts ride the ids fetch (no sync of their own). Pending counts
+        # are (array, from a decode step): held experts read are counted over
+        # decode steps only, whose weight reads bound the decode step
+        self._routed = model.routes_experts
+        self._route_pending: List[Tuple[jax.Array, bool]] = []
+        self._c_routes = {n: self.registry.counter(n) for n in (
+            "moe_pairs_routed", "moe_pairs_held", "moe_decode_experts_read")
+        } if self._routed else {}
         self._last_step_time: Optional[float] = None  # fused-horizon estimate
         self._straggler = StragglerPolicy(threshold=config.slow_step_threshold)
         # beam search selects from the fused step's top-k logprob pair, so the
@@ -1025,7 +1034,7 @@ class ServeEngine:
                 toks += [0] * (bucket - c_real)
                 read_row = self.cache.tables[slot : slot + 1]
                 write_row = self.cache.write_table_row(slot)[None, :]
-                logits, pools = self._chunk_step(
+                out = self._chunk_step(
                     self.params,
                     self.cache.pools,
                     jnp.asarray([toks], jnp.int32),
@@ -1035,7 +1044,8 @@ class ServeEngine:
                     jnp.asarray([c_real], jnp.int32),
                     jnp.asarray([min(n_ctx - 1 - cursor, c_real - 1)], jnp.int32),
                 )
-                self.cache.pools = pools
+                logits, self.cache.pools = out[:2]
+                self._route_pending.extend((c, False) for c in out[2:])
             self._c_pf_computed.inc(c_real)
             if cursor + c_real >= n_ctx:  # this chunk covered the last position
                 state.chunk_cursor = None
@@ -1379,6 +1389,9 @@ class ServeEngine:
                     logits_rows = np.asarray(
                         logits[:, : self.model.cfg.vocab], np.float32
                     )
+                if self._routed:
+                    self._route_pending.append((out[-1], True))
+                    self._count_routes()
             self._tokens_landed(k)
             if k > 1:
                 self._c_fused.inc(k)
@@ -1394,6 +1407,19 @@ class ServeEngine:
                                     logits_rows)
         wall = time.perf_counter() - wall0
         self._h_host.observe((wall - t_dev) / k)
+
+    def _count_routes(self) -> None:
+        """Add the routing counts of the steps since the last fetch to the
+        registry (one transfer of small arrays the steps already finished)."""
+        if self._route_pending:
+            arrays, decode = zip(*self._route_pending)
+            got = np.asarray(jax.device_get(list(arrays)))  # (steps, 3)
+            routed, held = got[:, :2].sum(axis=0).tolist()
+            self._c_routes["moe_pairs_routed"].inc(routed)
+            self._c_routes["moe_pairs_held"].inc(held)
+            self._c_routes["moe_decode_experts_read"].inc(
+                int(got[np.asarray(decode), 2].sum()))
+            self._route_pending = []
 
     def _commit_decode(self, decoding, k, ids, lps, lp_vals, lp_ids,
                        logits_rows) -> None:
@@ -1585,6 +1611,7 @@ class ServeEngine:
         straggler EMA, and reset allocator stats."""
         self.results = {}
         self.logits_of = {}
+        self._route_pending = []
         self.registry.reset()
         if self.trace is not None:
             self.trace.clear()
@@ -1612,6 +1639,8 @@ class ServeEngine:
                 "tuned_chunk_tokens": self.config.chunk_tokens,
                 "tuned_source": self.tuned.source,
             }
+        self._count_routes()
+        routes = {n: c.value for n, c in self._c_routes.items()}
         failed = [s for s in self.results.values() if s.error is not None]
         states = [s for s in self.results.values() if s.error is None]
         if not states:
@@ -1699,6 +1728,7 @@ class ServeEngine:
             "prefill_tokens_computed": self._c_pf_computed.value,
             "prefill_tokens_skipped": self._c_pf_skipped.value,
             **spec,
+            **routes,
             **self.cache.stats(),
             **tuning,
         }

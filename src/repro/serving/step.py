@@ -69,17 +69,21 @@ def _fused_decode(model, shard, attn_impl, kv_spec, vocab, params, caches,
     self-loops), so ungated slots ride the same program.
 
     Returns (next_tokens (B,) i32, logits (B, Vp), new_lens (B,) i32, caches,
-    chosen_lp (B,) f32[, new_gstate (B,) i32 when grammar]). ``chosen_lp`` is
+    chosen_lp (B,) f32[, new_gstate (B,) i32 when grammar][, routing counts
+    (3,) i32 when the model routes experts, last]). ``chosen_lp`` is
     log P(next_token | prefix) under the UNMASKED distribution — the per-branch
     cumulative score best-of-n ranks by (a grammar constrains selection, not
     the score).
     """
     active = slot_i32[0]
-    logits, caches = model.decode_step_paged(
+    stats = model.routes_experts
+    out = model.decode_step_paged(
         params, caches, tokens, block_tables, context_lens,
         shard=shard, attn_impl=attn_impl, kv_spec=kv_spec, active=active,
-        block_pages=block_pages,
+        block_pages=block_pages, stats=stats,
     )
+    logits, caches = out[:2]
+    routed = out[2:]
     mask = None
     if grammar is not None:
         gstate, gmask, gtrans = grammar
@@ -93,9 +97,9 @@ def _fused_decode(model, shard, attn_impl, kv_spec, vocab, params, caches,
     lp = jax.nn.log_softmax(logits[:, :vocab].astype(jnp.float32), axis=-1)
     chosen_lp = jnp.take_along_axis(lp, nxt[:, None], axis=-1)[:, 0]
     if grammar is None:
-        return nxt, logits, new_lens, caches, chosen_lp
+        return (nxt, logits, new_lens, caches, chosen_lp) + routed
     new_gstate = jnp.where(active > 0, gtrans[gstate, nxt], gstate)
-    return nxt, logits, new_lens, caches, chosen_lp, new_gstate
+    return (nxt, logits, new_lens, caches, chosen_lp, new_gstate) + routed
 
 
 def make_paged_serve_step(model, mesh=None, rules=None, attn_impl="auto",
@@ -142,9 +146,11 @@ def make_paged_serve_step(model, mesh=None, rules=None, attn_impl="auto",
             tokens, block_tables, context_lens, slot_f32, slot_i32,
             grammar=tuple(g) if grammar else None, block_pages=block_pages,
         )
-        if not logprobs_k:
-            return out
-        return out + (top_logprobs(out[1], vocab, logprobs_k),)
+        routed = out[6 if grammar else 5:]  # the routing counts stay last
+        out = out[:len(out) - len(routed)]
+        if logprobs_k:
+            out = out + (top_logprobs(out[1], vocab, logprobs_k),)
+        return out + routed
 
     return fused_serve_step
 
@@ -167,7 +173,8 @@ def make_paged_serve_multistep(model, k_steps: int, mesh=None, rules=None,
     costs zero extra host round-trips even fused), and the advanced gstate is
     returned after the chosen_lps. With ``logprobs_k > 0`` the scan
     additionally stacks the per-step top-k logprob pair ((K, B, k) vals +
-    ids), fetched in the same round as the ids.
+    ids), fetched in the same round as the ids. A routed model's counts of
+    the K steps come last, summed.
     """
     shard = Sharder(mesh, rules)
 
@@ -183,9 +190,9 @@ def make_paged_serve_multistep(model, k_steps: int, mesh=None, rules=None,
             )
             nxt, logits, new_lens, cs, chosen_lp = out[:5]
             new_gs = out[5] if grammar else gs
-            y = (nxt, chosen_lp) if not logprobs_k else (
-                nxt, chosen_lp, top_logprobs(logits, vocab, logprobs_k)
-            )
+            y = (nxt, chosen_lp, out[6 if grammar else 5:])
+            if logprobs_k:
+                y = y + (top_logprobs(logits, vocab, logprobs_k),)
             return (nxt, new_lens, new_gs, cs), y
 
         gs0 = g[0] if grammar else jnp.zeros_like(context_lens)
@@ -197,8 +204,8 @@ def make_paged_serve_multistep(model, k_steps: int, mesh=None, rules=None,
         if grammar:
             out = out + (gs,)
         if logprobs_k:
-            out = out + (ys[2],)
-        return out
+            out = out + (ys[3],)
+        return out + tuple(c.sum(axis=0) for c in ys[2])
 
     return fused_multistep
 
@@ -218,11 +225,13 @@ def make_chunked_prefill_step(model, mesh=None, rules=None, attn_impl="auto",
         pages (shared prefix included: the compute-skip path attends the
         donor's KV); ``write_tables`` nulls the non-writable entries so the
         chunk's scatter never lands in a page another sequence reads — the CoW
-        obligation discharged by table surgery instead of a copy."""
+        obligation discharged by table surgery instead of a copy. A routed
+        model returns its routing counts as a third item."""
         return model.decode_step_paged(
             params, caches, tokens, block_tables, cursors,
             shard=shard, attn_impl=attn_impl, kv_spec=kv_spec,
             write_tables=write_tables, n_new=n_new, last_index=last_index,
+            stats=model.routes_experts,
         )
 
     return chunk_prefill_step

@@ -112,6 +112,37 @@ def test_paged_prefill_chunk_compiles_for_v5e(one_chip, rep, width):
     assert "tpu_custom_call" in text
 
 
+# Mellum2's attention: 32 / 4 heads of 128, 256-token pages, a 1,024-token
+# window, a 32-row batch and a 69-page table (16,384 + 1,024 tokens)
+WIN_HQ, WIN_HKV, WIN_D, WIN_PAGE, WINDOW, WIN_B = 32, 4, 128, 256, 1024, 32
+
+
+@pytest.mark.parametrize("max_pages", [17, 69])
+@pytest.mark.parametrize("kernel", ["decode", "chunk"])
+def test_windowed_kernels_compile_for_v5e_on_a_five_page_grid(one_chip, kernel,
+                                                              max_pages):
+    """The windowed decode and chunk kernels compile for a v5e at Mellum2's
+    widths, and the page axis of their grids is ceil(1024 / 256) + 1 = 5
+    whatever the table's width."""
+    sds = lambda s, dt: _shape(one_chip, s, dt)
+    pool = sds((WIN_B * max_pages + 1, WIN_HKV, WIN_PAGE, WIN_D), jnp.bfloat16)
+    table = sds((WIN_B, max_pages), jnp.int32)
+    lens = sds((WIN_B,), jnp.int32)
+    if kernel == "decode":
+        fn = lambda *a: pa.paged_flash_decode(*a, window=WINDOW, interpret=False)
+        args = (sds((WIN_B, WIN_HQ, 1, WIN_D), jnp.bfloat16), pool, pool, table, lens)
+    else:
+        fn = lambda *a: pa.paged_flash_prefill_chunk(*a, window=WINDOW, interpret=False)
+        chunk = sds((1, WIN_HKV, 2 * WIN_PAGE, WIN_D), jnp.bfloat16)
+        args = (sds((1, WIN_HQ, 2 * WIN_PAGE, WIN_D), jnp.bfloat16), chunk, chunk,
+                pool, pool, sds((1, max_pages), jnp.int32), sds((1,), jnp.int32))
+    grids = [e.params["grid_mapping"].grid for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert [g[2] for g in grids] == [5]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and '"window":"1024"' in text
+
+
 def test_deployment_sized_block_table_compiles_for_v5e(one_chip):
     """Block tables ride scalar prefetch into SMEM: a 32-sequence batch at a
     4096-token context (32 x 257 int32 entries) must still fit."""
@@ -142,10 +173,20 @@ def _row_major_params(hlo: str):
             if _dims(m2m) == tuple(reversed(range(len(_dims(d)))))}
 
 
-def _step_program(sharding, rep: str, program: str):
+# a period of three windowed and one full layer, each with routed experts
+# (4 of 8 held), at the same pool geometry: Mellum2's program at tiny widths
+PERIOD_CFG = ModelConfig(
+    name="period-in-place", family="moe", n_layers=8, d_model=256, vocab=512,
+    n_heads=4, n_kv_heads=2, d_head=128, d_ff=256, n_experts=8, top_k=2,
+    experts_held=4, window=512,
+    layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
+    yarn_factor=16.0, yarn_original_max=8192)
+
+
+def _step_program(sharding, rep: str, program: str, cfg=STEP_CFG):
     """(jitted step, abstract args, abstract pool leaves) of one serving program
-    over a ``rep`` pool of STEP_CFG."""
-    model = Model(STEP_CFG)
+    over a ``rep`` pool of ``cfg``."""
+    model = Model(cfg)
     spec = KV_DTYPES[rep] if rep != "bf16" else None
     sds = lambda s, dt: _shape(sharding, s, dt)
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
@@ -154,7 +195,7 @@ def _step_program(sharding, rep: str, program: str):
         lambda: model.init_paged_cache(STEP_PAGES, STEP_PAGE, kv_spec=spec)))
     i32, b, mp = jnp.int32, STEP_B, STEP_MP
     slots = (sds((2, b), jnp.float32), sds((3, b), i32))
-    vocab = STEP_CFG.vocab
+    vocab = cfg.vocab
     if program == "decode":
         fn = jax.jit(make_paged_serve_step(model, attn_impl="pallas", kv_spec=spec,
                                            vocab=vocab), donate_argnums=(1, 2, 4))
@@ -222,11 +263,14 @@ def _pool_ops(hlo: str, leaves):
                 reach(callee, seen)
         return seen
 
-    # the layer scan: a loop that runs a kernel and no inner loop
+    # the layer scan: a loop that runs a kernel, and no inner loop that does
+    # (a routed layer's group metadata has small loops of its own)
+    runs_kernel = lambda body: bool(reach(body, set()) & kernels)
     in_layer_loop = set()
     for body in set().union(*bodies.values()):
         inside = reach(body, set())
-        if inside & kernels and not any(bodies.get(c) for c in inside):
+        if inside & kernels and not any(
+                runs_kernel(inner) for c in inside for inner in bodies.get(c, ())):
             in_layer_loop |= inside
     assert in_layer_loop, "no layer scan found in the program"
     found, dims = [], {}
@@ -252,6 +296,20 @@ def _pool_ops(hlo: str, leaves):
     return found
 
 
+def _check_in_place(one_chip, rep, program, cfg):
+    fn, args, leaves = _step_program(one_chip, rep, program, cfg)
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_ops(hlo, leaves) == []
+    nbytes = lambda leaf: math.prod(leaf.shape) * leaf.dtype.itemsize
+    row_major = _row_major_params(hlo)
+    layer_bytes = sum(nbytes(leaf) for leaf in leaves) // cfg.n_layers
+    relayout_bytes = sum(nbytes(leaf) for leaf in leaves if leaf.shape not in row_major)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes + 2 * relayout_bytes
+    return hlo
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk", "spec_window"])
 @pytest.mark.parametrize("rep", KV_REPS)
 def test_step_writes_pool_in_place_on_v5e(one_chip, monkeypatch, rep, program):
@@ -261,13 +319,15 @@ def test_step_writes_pool_in_place_on_v5e(one_chip, monkeypatch, rep, program):
     below one layer's pool, plus two buffers for each leaf the TPU holds in
     another layout than the kernels' (its relayout in and out)."""
     monkeypatch.setattr(pa, "use_interpret", lambda: False)
-    fn, args, leaves = _step_program(one_chip, rep, program)
-    compiled = fn.lower(*args).compile()
-    hlo = compiled.as_text()
-    assert "tpu_custom_call" in hlo
-    assert _pool_ops(hlo, leaves) == []
-    nbytes = lambda leaf: math.prod(leaf.shape) * leaf.dtype.itemsize
-    row_major = _row_major_params(hlo)
-    layer_bytes = sum(nbytes(leaf) for leaf in leaves) // STEP_CFG.n_layers
-    relayout_bytes = sum(nbytes(leaf) for leaf in leaves if leaf.shape not in row_major)
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes + 2 * relayout_bytes
+    _check_in_place(one_chip, rep, program, STEP_CFG)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_period_moe_step_writes_pool_in_place_on_v5e(one_chip, monkeypatch, program):
+    """The same law for a scan over periods of windowed and full layers with
+    routed experts: one flat pool of all eight layers, no pool-shaped copy;
+    the windowed kernels, the full ones and the grouped expert products
+    (megablox ``gmm``) all compile for the v5e."""
+    monkeypatch.setattr(pa, "use_interpret", lambda: False)
+    hlo = _check_in_place(one_chip, "bf16", program, PERIOD_CFG)
+    assert '"window":"512"' in hlo and "%gmm" in hlo
